@@ -48,7 +48,7 @@ def run(devices: int = 8, arch: str = "qwen15_05b", steps: int = 2,
         sys.path.insert(0, os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         from benchmarks.common import emit, time_fn
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_arch
     from repro.core.fp8 import TILE
     from repro.core.recipes import get_recipe

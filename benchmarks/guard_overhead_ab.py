@@ -50,7 +50,7 @@ def run(arch: str = "qwen15_05b", steps: int = 5, dry_run: bool = False):
         sys.path.insert(0, os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         from benchmarks.common import emit, time_fn
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_arch
     from repro.core.recipes import get_recipe
     from repro.data.pipeline import DataConfig, make_batch

@@ -42,7 +42,7 @@ def run(train_steps: int = 5, requests: int = 20, out_dir=None):
     except ModuleNotFoundError:      # invoked as `python benchmarks/...py`
         sys.path.insert(0, os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_arch
     from repro.core import quant as quant_stats
     from repro.core.recipes import get_recipe

@@ -55,7 +55,7 @@ def run(devices: int = 2, tokens: int = 512, d_model: int = 256,
         sys.path.insert(0, os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         from benchmarks.common import emit, ici_model_us
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core.fp8 import TILE
     from repro.core.moe import (MoEConfig, _round_up, moe_block,
                                 moe_block_overlapped)
@@ -86,11 +86,11 @@ def run(devices: int = 2, tokens: int = 512, d_model: int = 256,
         def body(x, wr, w13, w2):
             y, _ = block(recipe, cfg, x, wr, w13, w2, **kw)
             return y
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P(("data", "model"), None), P(None, None),
-                                   P("model", None, None),
-                                   P("model", None, None)),
-                         out_specs=P(("data", "model"), None))
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(("data", "model"), None), P(None, None),
+                                       P("model", None, None),
+                                       P("model", None, None)),
+                             out_specs=P(("data", "model"), None))
 
     # ---- real lowering check: the overlapped path must lower AND fuse the
     # per-chunk dispatch from 3 collectives into 1 ------------------------

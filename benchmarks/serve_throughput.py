@@ -269,18 +269,17 @@ def main():
             os.path.dirname(os.path.abspath(__file__))))
         from benchmarks.common import emit
     from repro.configs import get_arch
-    from repro.launch.mesh import make_production_mesh, make_test_mesh
+    from repro.launch.mesh import make_device_mesh, make_test_mesh
     from repro.launch.sharding import make_plan
-    from repro.models.lm import ParallelPlan, init_params
+    from repro.models.lm import init_params
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
         mesh = make_test_mesh()
-        plan = ParallelPlan(mesh=mesh, dp_axes=("data",))
     else:
-        mesh = make_production_mesh()
-        plan = make_plan(cfg, mesh)
+        mesh = make_device_mesh()
+    plan = make_plan(cfg, mesh)
     params = init_params(cfg, jax.random.key(0))
 
     print("recipe,preset,prefill,cache,finished,tok_s,p50_lat_s,p99_lat_s,"

@@ -22,13 +22,16 @@ RECIPES = ["bf16", "blockwise", "naive_fp8", "fp8_flow"]
 
 def run():
     # needs the 512-virtual-device mesh; jax may already be initialized with
-    # 1 device in this process -> re-exec the probe loop in a subprocess
+    # 1 device in this process -> re-exec the probe loop in a subprocess.
+    # The child is a CPU lowering tool pinned to the CPU backend: a parent
+    # that holds the chip never has a child that needs it.
     import subprocess
     import sys
     if os.environ.get("_REPRO_T23_CHILD") != "1":
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + " --xla_force_host_platform_device_count=512")
+        env["JAX_PLATFORMS"] = "cpu"
         env["_REPRO_T23_CHILD"] = "1"
         env.setdefault("PYTHONPATH", "src:.")
         out = subprocess.run(

@@ -110,9 +110,12 @@ def _q_row(recipe: Recipe, x, tag, fused=False) -> QTensor:
 def _t_direct(recipe: Recipe, q: QTensor) -> QTensor:
     """Scaling-aware direct transpose of the last two axes (casting-free)."""
     if recipe.use_pallas:
+        from repro.kernels import interpret_mode
         from repro.kernels.fp8_transpose import fp8_transpose_pallas
         E, M, K = q.shape
-        dt, st = jax.vmap(lambda d, s: fp8_transpose_pallas(d, s))(
+        interpret = interpret_mode()
+        dt, st = jax.vmap(lambda d, s: fp8_transpose_pallas(
+            d, s, interpret=interpret))(
             q.data, q.scale.reshape(E, M, K // TILE))
         return QTensor(dt, st, (1, 1, TILE))
     return transpose_direct(q)

@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import casts
 from repro.core.fp8 import TILE
 from repro.core.linear import (_q_row, _quant_weights, dequantize_exit,
@@ -160,8 +159,10 @@ def _take_rows(x, row_map, fill=0.0):
 
 def _permute_pad_fields(data, scale, row_map, use_pallas: bool):
     if use_pallas:
+        from repro.kernels import interpret_mode
         from repro.kernels.fused_permute_pad import fused_permute_pad_pallas
-        return fused_permute_pad_pallas(data, scale, row_map, row_map.shape[0])
+        return fused_permute_pad_pallas(data, scale, row_map, row_map.shape[0],
+                                        interpret=interpret_mode())
     return _take_rows(data, row_map), _take_rows(scale, row_map, fill=1.0)
 
 
@@ -232,7 +233,7 @@ def fp8_dispatch_naive(recipe: Recipe, x, row_map, T: int, ep_axis: str):
 def _a2a(t, axis_name):
     if axis_name is None:           # local EP=1 path (no mesh axis mapped)
         return t
-    EP = compat.axis_size(axis_name)
+    EP = jax.lax.axis_size(axis_name)
     shp = t.shape
     t = t.reshape(EP, shp[0] // EP, *shp[1:])
     t = jax.lax.all_to_all(t, axis_name, split_axis=0, concat_axis=0,
@@ -272,7 +273,7 @@ def moe_block(recipe: Recipe, cfg: MoEConfig, x, w_router, w13, w2):
     identity) — used when the whole train step is already inside a
     data-parallel shard_map (repro.dist) and no expert axis exists."""
     T, D = x.shape
-    EP = compat.axis_size(cfg.ep_axis) if cfg.ep_axis is not None else 1
+    EP = jax.lax.axis_size(cfg.ep_axis) if cfg.ep_axis is not None else 1
     E_loc = cfg.n_experts // EP
     assert E_loc * EP == cfg.n_experts, (cfg.n_experts, EP)
     k = cfg.top_k
@@ -491,7 +492,7 @@ def _decode_pipeline(recipe: Recipe, cfg: MoEConfig, x, w_router, w13, w2,
     compute runs (decode tokens never interact below the combine — chunking
     the batch is exact, modulo per-chunk capacity C_dec under overflow)."""
     T, D = x.shape
-    EP = compat.axis_size(cfg.ep_axis)
+    EP = jax.lax.axis_size(cfg.ep_axis)
     E_loc = cfg.n_experts // EP
     r = jax.lax.axis_index(cfg.ep_axis)
     k = cfg.top_k
@@ -689,7 +690,7 @@ def _overlap_chunks_autodiff(recipe, cfg, n, x, p, ids, w13, w2):
     structure (the fused-message + hoisted-cast pipeline is fp8_flow-only:
     for the baselines, per-chunk casts ARE the cost the paper counts)."""
     T, D = x.shape
-    EP = compat.axis_size(cfg.ep_axis)
+    EP = jax.lax.axis_size(cfg.ep_axis)
     E_loc = cfg.n_experts // EP
     k = cfg.top_k
     Tc, C_send, R, C_exp = _chunk_geometry(recipe, cfg, T, n, EP, E_loc)
@@ -726,7 +727,7 @@ def _overlap_chunks_autodiff(recipe, cfg, n, x, p, ids, w13, w2):
 
 def _flow_fwd_impl(recipe, cfg, n, x, p, ids, w13, w2):
     T, D = x.shape
-    EP = compat.axis_size(cfg.ep_axis)
+    EP = jax.lax.axis_size(cfg.ep_axis)
     E_loc = cfg.n_experts // EP
     assert E_loc * EP == cfg.n_experts, (cfg.n_experts, EP)
     k = cfg.top_k

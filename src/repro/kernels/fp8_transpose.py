@@ -1,6 +1,6 @@
 """Pallas TPU kernel: scaling-aware FP8 direct transpose (paper Algorithm 1).
 
-Grid: (M/128, K/128) — one 128x128 e4m3 block per step, resident in VMEM.
+Grid: (K/128, M/128) — one 128x128 e4m3 block per step, resident in VMEM.
 Per block:
   s_max  = max of the 128 row scales covering this block
   k_i    = log2(s_max / s_i)            (integer: scales are powers of two)
@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.fp8 import BLOCK, E4M3
+from repro.kernels import checked_interpret, out_struct
+from repro.kernels.quantize import put_lane, take_index
 
 _SIGN_MASK = 0x80
 _EXP_SHIFT = 3
@@ -33,12 +35,8 @@ def _rshift_rne(v: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
     floor = jnp.right_shift(v, n)
     rem = v - jnp.left_shift(floor, n)
     half = jnp.left_shift(jnp.int32(1), jnp.maximum(n - 1, 0))
-    round_up = jnp.where(
-        n > 0,
-        (rem > half) | ((rem == half) & ((floor & 1) == 1)),
-        False,
-    )
-    return floor + round_up.astype(jnp.int32)
+    round_up = (n > 0) & ((rem > half) | ((rem == half) & ((floor & 1) == 1)))
+    return floor + jnp.where(round_up, 1, 0)
 
 
 def _rebase_exponent(enc_u8: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
@@ -68,48 +66,55 @@ def _rebase_exponent(enc_u8: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
     return out.astype(jnp.uint8)
 
 
+def _f32_exponent(s):
+    """Biased exponent field of a positive f32 (exact for po2 scales)."""
+    return jnp.right_shift(jax.lax.bitcast_convert_type(s, jnp.int32), 23)
+
+
 def _transpose_kernel(x_ref, s_ref, xo_ref, so_ref):
-    """x_ref: (BLOCK, BLOCK) e4m3; s_ref: (BLOCK, 1) f32 row scales."""
-    s = s_ref[...]                                     # (BLOCK, 1) po2
-    s_max = jnp.max(s)
-    # k = log2(s_max / s): extract exponents via frexp (s = 0.5 * 2^(e))
-    _, e_s = jnp.frexp(s)
-    _, e_max = jnp.frexp(s_max)
-    k = (e_max - e_s).astype(jnp.int32)                # (BLOCK, 1), >= 0
+    """x_ref: (BLOCK, BLOCK) e4m3; s_ref: (BLOCK, nb_k) f32 row scales of
+    the block row; so_ref: (BLOCK, nb_m) scales of the output block row."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    s = take_index(s_ref[...], j, 1)                   # (BLOCK, 1) po2
+    s_max = jnp.max(s, axis=0, keepdims=True)          # (1, 1)
+    # k = log2(s_max / s): scales are powers of two, so it is the exponent
+    # difference
+    k = _f32_exponent(s_max) - _f32_exponent(s)        # (BLOCK, 1), >= 0
 
     enc = jax.lax.bitcast_convert_type(x_ref[...], jnp.uint8)
     rebased = _rebase_exponent(enc, k)                 # rows rebased onto s_max
     out = jax.lax.bitcast_convert_type(rebased, E4M3).T
     xo_ref[...] = out
-    so_ref[...] = jnp.full((BLOCK, 1), s_max, jnp.float32)
+    put_lane(so_ref, i, jnp.broadcast_to(s_max, (BLOCK, 1)))
 
 
 def fp8_transpose_pallas(data: jax.Array, scale: jax.Array, *,
-                         interpret: bool = True):
+                         interpret: bool):
     """data: (M, K) e4m3 row-wise; scale: (M, K/BLOCK) f32 po2.
 
     Returns (data_t: (K, M) e4m3, scale_t: (K, M/BLOCK) f32) with the
-    transposed tensor quantized at block-aligned scales.
+    transposed tensor quantized at block-aligned scales.  The grid walks M
+    fastest so each output block row's scale block stays resident.
     """
     M, K = data.shape
     assert M % BLOCK == 0 and K % BLOCK == 0, (M, K)
     nb_m, nb_k = M // BLOCK, K // BLOCK
 
     out_shapes = (
-        jax.ShapeDtypeStruct((K, M), data.dtype),
-        jax.ShapeDtypeStruct((K, nb_m), jnp.float32),
+        out_struct((K, M), data.dtype, data, scale),
+        out_struct((K, nb_m), jnp.float32, data, scale),
     )
     return pl.pallas_call(
         _transpose_kernel,
-        grid=(nb_m, nb_k),
+        grid=(nb_k, nb_m),
         in_specs=[
-            pl.BlockSpec((BLOCK, BLOCK), lambda i, j: (i, j)),
-            pl.BlockSpec((BLOCK, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((BLOCK, BLOCK), lambda j, i: (i, j)),
+            pl.BlockSpec((BLOCK, nb_k), lambda j, i: (i, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((BLOCK, BLOCK), lambda i, j: (j, i)),
-            pl.BlockSpec((BLOCK, 1), lambda i, j: (j, i)),
+            pl.BlockSpec((BLOCK, BLOCK), lambda j, i: (j, i)),
+            pl.BlockSpec((BLOCK, nb_m), lambda j, i: (j, 0)),
         ),
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(data, scale)

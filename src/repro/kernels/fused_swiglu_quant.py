@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.fp8 import E4M3, E4M3_MAX, TILE
-from repro.kernels.quantize import kernel_po2_scale
+from repro.kernels import checked_interpret, out_struct
+from repro.kernels.quantize import kernel_po2_scale, put_lane
 
 ROWS = 128
 
@@ -31,18 +32,18 @@ def _swiglu_quant_kernel(h_ref, data_ref, scale_ref):
     amax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
     s = kernel_po2_scale(amax)
     data_ref[...] = jnp.clip(y / s, -E4M3_MAX, E4M3_MAX).astype(E4M3)
-    scale_ref[...] = s
+    put_lane(scale_ref, pl.program_id(1), s)
 
 
-def fused_swiglu_quant_pallas(h: jax.Array, *, interpret: bool = True):
+def fused_swiglu_quant_pallas(h: jax.Array, *, interpret: bool):
     """h: (M, 2F) bf16 [gate | up] -> (data (M, F) e4m3, scale (M, F/TILE))."""
     M, twoF = h.shape
     F = twoF // 2
     assert M % ROWS == 0 and F % TILE == 0, (M, F)
     nb_f = F // TILE
     out_shapes = (
-        jax.ShapeDtypeStruct((M, F), E4M3),
-        jax.ShapeDtypeStruct((M, nb_f), jnp.float32),
+        out_struct((M, F), E4M3, h),
+        out_struct((M, nb_f), jnp.float32, h),
     )
     return pl.pallas_call(
         _swiglu_quant_kernel,
@@ -52,8 +53,8 @@ def fused_swiglu_quant_pallas(h: jax.Array, *, interpret: bool = True):
         ],
         out_specs=(
             pl.BlockSpec((ROWS, TILE), lambda i, j: (i, j)),
-            pl.BlockSpec((ROWS, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((ROWS, nb_f), lambda i, j: (i, 0)),
         ),
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(h.reshape(M, 2, F))

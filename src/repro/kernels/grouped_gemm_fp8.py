@@ -31,6 +31,13 @@ Two layouts:
   payload is there, so callers that need row-exact zeros must zero-pad the
   dead rows (the fused permute+pad dispatch layout guarantees this).
 
+Scales ride whole-row blocks (a TPU block's last dim must be a 128-multiple
+or the whole array dim): the activation-scale block (1, BM, K/TILE) and the
+weight-scale block (1, K/TILE, N/TILE) stay resident while the K loop
+selects its column / element (``quantize.take_index``), and a quantizing
+epilogue writes its scale column into lane n of a resident (1, BM, N/BN)
+block (``quantize.put_lane``).
+
 The masked GEMM-1 variant fuses the inter-GEMM SwiGLU + row-wise e4m3
 re-quantize into the ``k == nk-1`` epilogue (paper §3.3.2 taken into the
 kernel layer): gate/up column tiles accumulate in two scratches, the
@@ -47,41 +54,53 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.fp8 import TILE
-from repro.kernels.quantize import kernel_po2_scale
+from repro.core.fp8 import E4M3, E4M3_MAX, TILE
+from repro.kernels import checked_interpret, out_struct
+from repro.kernels.quantize import kernel_po2_scale, put_lane, take_index
 
 BM = 128
 BN = 128
 BK = TILE  # must equal the scale tile
 
 
+def _scales(sx_ref, sw_ref, n, k):
+    """(BM, 1) activation scales of K-tile k and the (1, 1) weight scale of
+    block (k, n), read out of their resident whole-row blocks."""
+    sx = take_index(sx_ref[0], k, 1)
+    sw = take_index(take_index(sw_ref[0], k, 0), n, 1)
+    return sx, sw
+
+
+def _scaled_partial(x_ref, w, sx_ref, sw_ref, n, k):
+    """One K-step: the fp8 tile product (f32 accumulate) times its scales."""
+    x = x_ref[0].astype(jnp.float32)                   # (BM, BK) fp8 payload
+    partial = jax.lax.dot(x, w.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)  # f32 accum
+    sx, sw = _scales(sx_ref, sw_ref, n, k)
+    return partial * (sx * sw)
+
+
 def _gg_kernel(x_ref, sx_ref, w_ref, sw_ref, o_ref, acc_ref, *, nk: int):
-    k = pl.program_id(3)
+    n, k = pl.program_id(2), pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)                   # (BM, BK) fp8 payload
-    w = w_ref[0].astype(jnp.float32)                   # (BK, BN)
-    partial = jax.lax.dot(x, w,
-                          precision=jax.lax.Precision.HIGHEST)  # f32 accum
-    sx = sx_ref[0]                                     # (BM, 1) act scales
-    sw = sw_ref[0, 0, 0]                               # scalar weight scale
-    acc_ref[...] += partial * (sx * sw)
+    acc_ref[...] += _scaled_partial(x_ref, w_ref[0], sx_ref, sw_ref, n, k)
 
     @pl.when(k == nk - 1)
     def _done():
         o_ref[0, ...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _quant_epilogue(acc, o_ref, os_ref):
-    """Row-wise e4m3 + po2-scale quantization of a (BM, BN=TILE) f32 tile."""
-    from repro.core.fp8 import E4M3, E4M3_MAX
-    amax = jnp.max(jnp.abs(acc), axis=-1, keepdims=True)
+def _quant_tile(y, o_ref, os_ref, n):
+    """Row-wise e4m3 + po2-scale quantization of a (BM, BN=TILE) f32 tile;
+    the scale column goes to lane n of the resident scale block."""
+    amax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
     s = kernel_po2_scale(amax)
-    o_ref[0, ...] = jnp.clip(acc / s, -E4M3_MAX, E4M3_MAX).astype(E4M3)
-    os_ref[0, ...] = s
+    o_ref[0, ...] = jnp.clip(y / s, -E4M3_MAX, E4M3_MAX).astype(E4M3)
+    put_lane(os_ref.at[0], n, s)
 
 
 def _gg_quant_kernel(x_ref, sx_ref, w_ref, sw_ref, o_ref, os_ref, acc_ref,
@@ -89,22 +108,17 @@ def _gg_quant_kernel(x_ref, sx_ref, w_ref, sw_ref, o_ref, os_ref, acc_ref,
     """Same as _gg_kernel but the epilogue quantizes the (BM, BN=TILE) output
     tile to e4m3 + a po2 scale column — the 'fused epilogue quantization' that
     keeps Dgrad outputs in FP8 without an explicit cast kernel (§3.2)."""
-    k = pl.program_id(3)
+    n, k = pl.program_id(2), pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    partial = jax.lax.dot(x, w, precision=jax.lax.Precision.HIGHEST)
-    sx = sx_ref[0]
-    sw = sw_ref[0, 0, 0]
-    acc_ref[...] += partial * (sx * sw)
+    acc_ref[...] += _scaled_partial(x_ref, w_ref[0], sx_ref, sw_ref, n, k)
 
     @pl.when(k == nk - 1)
     def _done():
-        _quant_epilogue(acc_ref[...], o_ref, os_ref)
+        _quant_tile(acc_ref[...], o_ref, os_ref, n)
 
 
 def _assert_quant_out_tiling():
@@ -118,46 +132,51 @@ def _assert_quant_out_tiling():
         "the per-(row, BN-tile) scales are exposed as (1, TILE) row tiles")
 
 
+def _in_specs(nk: int, nn: int, prefetch: bool):
+    """x / sx / w / sw block specs (index maps see the scalar-prefetch ref
+    as a trailing arg when ``prefetch``)."""
+    def im(f):
+        return (lambda e, m, n, k, mm: f(e, m, n, k)) if prefetch else f
+    return [
+        pl.BlockSpec((1, BM, BK), im(lambda e, m, n, k: (e, m, k))),
+        pl.BlockSpec((1, BM, nk), im(lambda e, m, n, k: (e, m, 0))),
+        pl.BlockSpec((1, BK, BN), im(lambda e, m, n, k: (e, k, n))),
+        pl.BlockSpec((1, nk, nn), im(lambda e, m, n, k: (e, 0, 0))),
+    ]
+
+
+def _out_specs(nn: int, quant_out: bool, prefetch: bool):
+    def im(f):
+        return (lambda e, m, n, k, mm: f(e, m, n, k)) if prefetch else f
+    data = pl.BlockSpec((1, BM, BN), im(lambda e, m, n, k: (e, m, n)))
+    if not quant_out:
+        return data
+    return (data, pl.BlockSpec((1, BM, nn), im(lambda e, m, n, k: (e, m, 0))))
+
+
+def _out_shape(E, C, N, out_dtype, quant_out: bool, operands):
+    if not quant_out:
+        return out_struct((E, C, N), out_dtype, *operands)
+    _assert_quant_out_tiling()
+    return (out_struct((E, C, N), E4M3, *operands),
+            out_struct((E, C, N // BN), jnp.float32, *operands))
+
+
 def grouped_gemm_fp8_pallas(x, sx, w, sw, *, out_dtype=jnp.bfloat16,
-                            quant_out: bool = False, interpret: bool = True):
+                            quant_out: bool = False, interpret: bool):
     E, C, K = x.shape
     _, _, N = w.shape
     assert C % BM == 0 and N % BN == 0 and K % BK == 0, (C, K, N)
-    nk = K // BK
-    grid = (E, C // BM, N // BN, nk)
-    in_specs = [
-        pl.BlockSpec((1, BM, BK), lambda e, m, n, k: (e, m, k)),
-        pl.BlockSpec((1, BM, 1), lambda e, m, n, k: (e, m, k)),
-        pl.BlockSpec((1, BK, BN), lambda e, m, n, k: (e, k, n)),
-        pl.BlockSpec((1, 1, 1), lambda e, m, n, k: (e, k, n)),
-    ]
-    if not quant_out:
-        return pl.pallas_call(
-            functools.partial(_gg_kernel, nk=nk),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, BM, BN), lambda e, m, n, k: (e, m, n)),
-            out_shape=jax.ShapeDtypeStruct((E, C, N), out_dtype),
-            scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
-            interpret=interpret,
-        )(x, sx, w, sw)
-
-    from repro.core.fp8 import E4M3
-    _assert_quant_out_tiling()
+    nk, nn = K // BK, N // BN
+    kernel = _gg_quant_kernel if quant_out else _gg_kernel
     return pl.pallas_call(
-        functools.partial(_gg_quant_kernel, nk=nk),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, BM, BN), lambda e, m, n, k: (e, m, n)),
-            pl.BlockSpec((1, BM, 1), lambda e, m, n, k: (e, m, n)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, C, N), E4M3),
-            jax.ShapeDtypeStruct((E, C, N // BN), jnp.float32),
-        ),
+        functools.partial(kernel, nk=nk),
+        grid=(E, C // BM, nn, nk),
+        in_specs=_in_specs(nk, nn, prefetch=False),
+        out_specs=_out_specs(nn, quant_out, prefetch=False),
+        out_shape=_out_shape(E, C, N, out_dtype, quant_out, (x, sx, w, sw)),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
-        interpret=interpret,
+        interpret=checked_interpret(interpret),
     )(x, sx, w, sw)
 
 
@@ -167,9 +186,7 @@ def grouped_gemm_fp8_pallas(x, sx, w, sw, *, out_dtype=jnp.bfloat16,
 # ---------------------------------------------------------------------------
 def _gg_masked_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref, o_ref, acc_ref,
                       *, nk: int):
-    e = pl.program_id(0)
-    m = pl.program_id(1)
-    k = pl.program_id(3)
+    e, m, n, k = (pl.program_id(i) for i in range(4))
     live = m * BM < mm_ref[e]
 
     @pl.when(live & (k == 0))
@@ -178,10 +195,7 @@ def _gg_masked_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref, o_ref, acc_ref,
 
     @pl.when(live)
     def _step():
-        x = x_ref[0].astype(jnp.float32)
-        w = w_ref[0].astype(jnp.float32)
-        partial = jax.lax.dot(x, w, precision=jax.lax.Precision.HIGHEST)
-        acc_ref[...] += partial * (sx_ref[0] * sw_ref[0, 0, 0])
+        acc_ref[...] += _scaled_partial(x_ref, w_ref[0], sx_ref, sw_ref, n, k)
 
     @pl.when(k == nk - 1)
     def _done():
@@ -193,9 +207,7 @@ def _gg_masked_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref, o_ref, acc_ref,
 
 def _gg_masked_quant_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref, o_ref,
                             os_ref, acc_ref, *, nk: int):
-    e = pl.program_id(0)
-    m = pl.program_id(1)
-    k = pl.program_id(3)
+    e, m, n, k = (pl.program_id(i) for i in range(4))
     live = m * BM < mm_ref[e]
 
     @pl.when(live & (k == 0))
@@ -204,16 +216,13 @@ def _gg_masked_quant_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref, o_ref,
 
     @pl.when(live)
     def _step():
-        x = x_ref[0].astype(jnp.float32)
-        w = w_ref[0].astype(jnp.float32)
-        partial = jax.lax.dot(x, w, precision=jax.lax.Precision.HIGHEST)
-        acc_ref[...] += partial * (sx_ref[0] * sw_ref[0, 0, 0])
+        acc_ref[...] += _scaled_partial(x_ref, w_ref[0], sx_ref, sw_ref, n, k)
 
     @pl.when(k == nk - 1)
     def _done():
         # dead tiles: acc==0 -> amax==0 -> scale 1.0, payload 0 — the exact
         # bits the padded quantizing epilogue emits for zero rows.
-        _quant_epilogue(jnp.where(live, acc_ref[...], 0.0), o_ref, os_ref)
+        _quant_tile(jnp.where(live, acc_ref[...], 0.0), o_ref, os_ref, n)
 
 
 def _gg_masked_swiglu_quant_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref,
@@ -221,15 +230,14 @@ def _gg_masked_swiglu_quant_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref,
                                    *, nk: int):
     """Masked grouped GEMM-1 with the SwiGLU + row-wise re-quantize fused
     into the last K-step: w13 arrives reshaped (E, K, 2, F) so ONE operand
-    block carries both the gate (half 0) and up (half 1) column tiles.  The
-    epilogue rounds both accumulators through bf16 first — bit-identical to
-    the unfused path's materialized bf16 island h — then quantizes
-    silu(gate)*up per (row, TILE)-tile."""
-    from repro.core.fp8 import E4M3, E4M3_MAX
-
-    e = pl.program_id(0)
-    m = pl.program_id(1)
-    k = pl.program_id(3)
+    block carries both the gate (half 0) and up (half 1) column tiles, and
+    its scales (E, K/TILE, 2*F/TILE) hold the gate tiles' scales in columns
+    [0, F/TILE) and the up tiles' after them.  The epilogue rounds both
+    accumulators through bf16 first — bit-identical to the unfused path's
+    materialized bf16 island h — then quantizes silu(gate)*up per
+    (row, TILE)-tile."""
+    e, m, n, k = (pl.program_id(i) for i in range(4))
+    nf = pl.num_programs(2)
     live = m * BM < mm_ref[e]
 
     @pl.when(live & (k == 0))
@@ -239,14 +247,10 @@ def _gg_masked_swiglu_quant_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref,
 
     @pl.when(live)
     def _step():
-        x = x_ref[0].astype(jnp.float32)
-        wg = w_ref[0, :, 0, :].astype(jnp.float32)     # (BK, BN) gate cols
-        wu = w_ref[0, :, 1, :].astype(jnp.float32)     # (BK, BN) up cols
-        sx = sx_ref[0]
-        accg_ref[...] += jax.lax.dot(
-            x, wg, precision=jax.lax.Precision.HIGHEST) * (sx * sw_ref[0, 0, 0, 0])
-        accu_ref[...] += jax.lax.dot(
-            x, wu, precision=jax.lax.Precision.HIGHEST) * (sx * sw_ref[0, 0, 1, 0])
+        accg_ref[...] += _scaled_partial(x_ref, w_ref[0, :, 0, :], sx_ref,
+                                         sw_ref, n, k)        # gate cols
+        accu_ref[...] += _scaled_partial(x_ref, w_ref[0, :, 1, :], sx_ref,
+                                         sw_ref, nf + n, k)   # up cols
 
     @pl.when(k == nk - 1)
     def _done():
@@ -256,73 +260,37 @@ def _gg_masked_swiglu_quant_kernel(mm_ref, x_ref, sx_ref, w_ref, sw_ref,
         # fused epilogue is BITWISE the unfused h -> swiglu+quant kernel pair
         g = g.astype(jnp.bfloat16).astype(jnp.float32)
         u = u.astype(jnp.bfloat16).astype(jnp.float32)
-        y = (g * jax.lax.logistic(g)) * u
-        amax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
-        s = kernel_po2_scale(amax)
-        o_ref[0, ...] = jnp.clip(y / s, -E4M3_MAX, E4M3_MAX).astype(E4M3)
-        os_ref[0, ...] = s
-
-
-def _masked_specs(extra=0):
-    """in_specs shared by the masked kernels (index maps see the prefetched
-    scalar ref as a trailing arg)."""
-    return [
-        pl.BlockSpec((1, BM, BK), lambda e, m, n, k, mm: (e, m, k)),
-        pl.BlockSpec((1, BM, 1), lambda e, m, n, k, mm: (e, m, k)),
-    ]
+        _quant_tile((g * jax.lax.logistic(g)) * u, o_ref, os_ref, n)
 
 
 def masked_grouped_gemm_fp8_pallas(x, sx, w, sw, masked_m, *,
                                    out_dtype=jnp.bfloat16,
                                    quant_out: bool = False,
-                                   interpret: bool = True):
+                                   interpret: bool):
     """Masked grouped GEMM: tiles with m*BM >= masked_m[e] are compute
     no-ops (zeros written in the epilogue)."""
     E, C, K = x.shape
     _, _, N = w.shape
     assert C % BM == 0 and N % BN == 0 and K % BK == 0, (C, K, N)
     assert masked_m.shape == (E,) and masked_m.dtype == jnp.int32, masked_m
-    nk = K // BK
-    grid = (E, C // BM, N // BN, nk)
-    in_specs = _masked_specs() + [
-        pl.BlockSpec((1, BK, BN), lambda e, m, n, k, mm: (e, k, n)),
-        pl.BlockSpec((1, 1, 1), lambda e, m, n, k, mm: (e, k, n)),
-    ]
-    if not quant_out:
-        gs = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, BM, BN),
-                                   lambda e, m, n, k, mm: (e, m, n)),
-            scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)])
-        return pl.pallas_call(
-            functools.partial(_gg_masked_kernel, nk=nk),
-            grid_spec=gs,
-            out_shape=jax.ShapeDtypeStruct((E, C, N), out_dtype),
-            interpret=interpret,
-        )(masked_m, x, sx, w, sw)
-
-    from repro.core.fp8 import E4M3
-    _assert_quant_out_tiling()
+    nk, nn = K // BK, N // BN
+    kernel = _gg_masked_quant_kernel if quant_out else _gg_masked_kernel
     gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, BM, BN), lambda e, m, n, k, mm: (e, m, n)),
-            pl.BlockSpec((1, BM, 1), lambda e, m, n, k, mm: (e, m, n)),
-        ),
+        num_scalar_prefetch=1, grid=(E, C // BM, nn, nk),
+        in_specs=_in_specs(nk, nn, prefetch=True),
+        out_specs=_out_specs(nn, quant_out, prefetch=True),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_gg_masked_quant_kernel, nk=nk),
+        functools.partial(kernel, nk=nk),
         grid_spec=gs,
-        out_shape=(
-            jax.ShapeDtypeStruct((E, C, N), E4M3),
-            jax.ShapeDtypeStruct((E, C, N // BN), jnp.float32),
-        ),
-        interpret=interpret,
+        out_shape=_out_shape(E, C, N, out_dtype, quant_out,
+                             (x, sx, w, sw, masked_m)),
+        interpret=checked_interpret(interpret),
     )(masked_m, x, sx, w, sw)
 
 
 def masked_grouped_gemm_swiglu_quant_pallas(x, sx, w13, sw13, masked_m, *,
-                                            interpret: bool = True):
+                                            interpret: bool):
     """Masked grouped GEMM-1 with fused SwiGLU + e4m3 re-quantize epilogue.
 
     x    : (E, C, K) e4m3, row-wise scales sx (E, C, K/TILE)
@@ -333,36 +301,25 @@ def masked_grouped_gemm_swiglu_quant_pallas(x, sx, w13, sw13, masked_m, *,
     (E, K, 2, F) reshape, so ONE HBM operand (one BlockSpec) feeds both
     accumulators — no duplicate operand declaration.
     """
-    from repro.core.fp8 import E4M3
-
     E, C, K = x.shape
     twoF = w13.shape[-1]
     F = twoF // 2
     assert C % BM == 0 and F % BN == 0 and K % BK == 0, (C, K, F)
     assert masked_m.shape == (E,) and masked_m.dtype == jnp.int32, masked_m
-    _assert_quant_out_tiling()
-    nk = K // BK
+    nk, nf = K // BK, F // BN
     w4 = w13.reshape(E, K, 2, F)
-    sw4 = sw13.reshape(E, K // TILE, 2, F // TILE)
-    grid = (E, C // BM, F // BN, nk)
+    specs = _in_specs(nk, 2 * nf, prefetch=True)
+    specs[2] = pl.BlockSpec((1, BK, 2, BN), lambda e, m, n, k, mm: (e, k, 0, n))
     gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid,
-        in_specs=_masked_specs() + [
-            pl.BlockSpec((1, BK, 2, BN), lambda e, m, n, k, mm: (e, k, 0, n)),
-            pl.BlockSpec((1, 1, 2, 1), lambda e, m, n, k, mm: (e, k, 0, n)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, BM, BN), lambda e, m, n, k, mm: (e, m, n)),
-            pl.BlockSpec((1, BM, 1), lambda e, m, n, k, mm: (e, m, n)),
-        ),
+        num_scalar_prefetch=1, grid=(E, C // BM, nf, nk),
+        in_specs=specs,
+        out_specs=_out_specs(nf, True, prefetch=True),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32),
                         pltpu.VMEM((BM, BN), jnp.float32)])
     return pl.pallas_call(
         functools.partial(_gg_masked_swiglu_quant_kernel, nk=nk),
         grid_spec=gs,
-        out_shape=(
-            jax.ShapeDtypeStruct((E, C, F), E4M3),
-            jax.ShapeDtypeStruct((E, C, F // TILE), jnp.float32),
-        ),
-        interpret=interpret,
-    )(masked_m, x, sx, w4, sw4)
+        out_shape=_out_shape(E, C, F, None, True,
+                             (x, sx, w13, sw13, masked_m)),
+        interpret=checked_interpret(interpret),
+    )(masked_m, x, sx, w4, sw13)

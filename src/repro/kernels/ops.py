@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernel suite.
 
-On this CPU container the kernels execute in interpret mode (the kernel body
-runs in Python, validating TPU semantics); on a TPU runtime set
-``REPRO_PALLAS_INTERPRET=0`` (or rely on the backend default) to compile them
-to Mosaic.  Every wrapper has a matching pure-jnp oracle in ``ref.py``.
+On the CPU backend the kernels execute in interpret mode (the kernel body
+runs in Python, validating TPU semantics); on a TPU they are compiled to
+Mosaic (``repro.kernels.interpret_mode``).  Every wrapper has a matching
+pure-jnp oracle in ``ref.py``.
 
 Alignment contract: the kernels require 128-aligned blocks, but MoE capacity
 is only rounded to 8 on the decode path (``moe._round_up(..., 8)``), so the
@@ -22,13 +22,13 @@ Masked variants take the per-expert live-row counts ``masked_m`` (int32
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.quant import QTensor, row_tile
 from repro.core.fp8 import TILE
+from repro.kernels import interpret_mode
 from repro.kernels.fp8_transpose import fp8_transpose_pallas
 from repro.kernels.fused_permute_pad import fused_permute_pad_pallas
 from repro.kernels.fused_swiglu_quant import fused_swiglu_quant_pallas
@@ -43,13 +43,6 @@ from repro.kernels.grouped_gemm_nt_fp8 import (
     masked_grouped_gemm_nt_fp8_pallas,
 )
 from repro.kernels.quantize import ROWS, quantize_rowwise_pallas
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 def _round_up(n: int, m: int) -> int:
@@ -79,70 +72,62 @@ def _pad_q_axis(q: QTensor, axis: int, block: int) -> QTensor:
                    _pad_rows(q.scale, axis, n_to, value=1.0), q.tile)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize_rowwise(x: jax.Array, interpret: bool | None = None) -> QTensor:
-    interpret = _interpret_default() if interpret is None else interpret
+@jax.jit
+def quantize_rowwise(x: jax.Array) -> QTensor:
     M = x.shape[0]
     data, scale = quantize_rowwise_pallas(
-        _pad_rows(x, 0, _round_up(M, ROWS)), interpret=interpret)
+        _pad_rows(x, 0, _round_up(M, ROWS)), interpret=interpret_mode())
     return QTensor(data=data[:M], scale=scale[:M], tile=row_tile(2))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fp8_transpose(q: QTensor, interpret: bool | None = None) -> QTensor:
-    interpret = _interpret_default() if interpret is None else interpret
-    data, scale = fp8_transpose_pallas(q.data, q.scale, interpret=interpret)
+@jax.jit
+def fp8_transpose(q: QTensor) -> QTensor:
+    data, scale = fp8_transpose_pallas(q.data, q.scale,
+                                       interpret=interpret_mode())
     return QTensor(data=data, scale=scale, tile=row_tile(2))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_swiglu_quant(h: jax.Array, interpret: bool | None = None) -> QTensor:
-    interpret = _interpret_default() if interpret is None else interpret
+@jax.jit
+def fused_swiglu_quant(h: jax.Array) -> QTensor:
     M = h.shape[0]
     data, scale = fused_swiglu_quant_pallas(
-        _pad_rows(h, 0, _round_up(M, ROWS)), interpret=interpret)
+        _pad_rows(h, 0, _round_up(M, ROWS)), interpret=interpret_mode())
     return QTensor(data=data[:M], scale=scale[:M], tile=row_tile(2))
 
 
-@functools.partial(jax.jit, static_argnames=("n_out", "interpret"))
-def fused_permute_pad(q: QTensor, row_map: jax.Array, n_out: int,
-                      interpret: bool | None = None) -> QTensor:
-    interpret = _interpret_default() if interpret is None else interpret
+@functools.partial(jax.jit, static_argnames=("n_out",))
+def fused_permute_pad(q: QTensor, row_map: jax.Array, n_out: int) -> QTensor:
     data, scale = fused_permute_pad_pallas(q.data, q.scale, row_map, n_out,
-                                           interpret=interpret)
+                                           interpret=interpret_mode())
     return QTensor(data=data, scale=scale, tile=row_tile(2))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def grouped_gemm_fp8(qx: QTensor, qw: QTensor, interpret: bool | None = None):
+@jax.jit
+def grouped_gemm_fp8(qx: QTensor, qw: QTensor):
     """qx: (E, C, K) row-wise; qw: (E, K, N) block-wise -> (E, C, N) bf16."""
-    interpret = _interpret_default() if interpret is None else interpret
     C = qx.data.shape[1]
     qx = _pad_q_axis(qx, 1, BM)
     out = grouped_gemm_fp8_pallas(qx.data, qx.scale, qw.data, qw.scale,
-                                  interpret=interpret)
+                                  interpret=interpret_mode())
     return out[:, :C]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def grouped_gemm_fp8_quant_out(qx: QTensor, qw: QTensor,
-                               interpret: bool | None = None) -> QTensor:
+@jax.jit
+def grouped_gemm_fp8_quant_out(qx: QTensor, qw: QTensor) -> QTensor:
     """Grouped GEMM whose epilogue quantizes straight to e4m3 (Dgrad path)."""
-    interpret = _interpret_default() if interpret is None else interpret
     C = qx.data.shape[1]
     qx = _pad_q_axis(qx, 1, BM)
     data, scale = grouped_gemm_fp8_pallas(qx.data, qx.scale, qw.data, qw.scale,
-                                          quant_out=True, interpret=interpret)
+                                          quant_out=True,
+                                          interpret=interpret_mode())
     return QTensor(data=data[:, :C], scale=scale[:, :C], tile=row_tile(3))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def grouped_gemm_nt_fp8(qa: QTensor, qb: QTensor,
-                        interpret: bool | None = None):
+@jax.jit
+def grouped_gemm_nt_fp8(qa: QTensor, qb: QTensor):
     """qa: (E, M, C), qb: (E, N, C) both row-wise over C -> (E, M, N) f32."""
-    interpret = _interpret_default() if interpret is None else interpret
     return grouped_gemm_nt_fp8_pallas(qa.data, qa.scale, qb.data, qb.scale,
-                                      interpret=interpret)
+                                      interpret=interpret_mode())
 
 
 # ---------------------------------------------------------------------------
@@ -152,67 +137,59 @@ def _use_padded(expected_m, C: int) -> bool:
     return expected_m is not None and expected_m >= C
 
 
-@functools.partial(jax.jit, static_argnames=("expected_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("expected_m",))
 def grouped_gemm_fp8_masked(qx: QTensor, qw: QTensor, masked_m: jax.Array,
-                            expected_m: int | None = None,
-                            interpret: bool | None = None):
+                            expected_m: int | None = None):
     """Masked grouped GEMM: capacity tiles beyond masked_m[e] skip the MXU."""
-    interpret = _interpret_default() if interpret is None else interpret
     C = qx.data.shape[1]
     if _use_padded(expected_m, C):
-        return grouped_gemm_fp8(qx, qw, interpret=interpret)
+        return grouped_gemm_fp8(qx, qw)
     qx = _pad_q_axis(qx, 1, BM)
     out = masked_grouped_gemm_fp8_pallas(
         qx.data, qx.scale, qw.data, qw.scale, masked_m.astype(jnp.int32),
-        interpret=interpret)
+        interpret=interpret_mode())
     return out[:, :C]
 
 
-@functools.partial(jax.jit, static_argnames=("expected_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("expected_m",))
 def grouped_gemm_fp8_masked_quant_out(qx: QTensor, qw: QTensor,
                                       masked_m: jax.Array,
-                                      expected_m: int | None = None,
-                                      interpret: bool | None = None) -> QTensor:
-    interpret = _interpret_default() if interpret is None else interpret
+                                      expected_m: int | None = None) -> QTensor:
     C = qx.data.shape[1]
     if _use_padded(expected_m, C):
-        return grouped_gemm_fp8_quant_out(qx, qw, interpret=interpret)
+        return grouped_gemm_fp8_quant_out(qx, qw)
     qx = _pad_q_axis(qx, 1, BM)
     data, scale = masked_grouped_gemm_fp8_pallas(
         qx.data, qx.scale, qw.data, qw.scale, masked_m.astype(jnp.int32),
-        quant_out=True, interpret=interpret)
+        quant_out=True, interpret=interpret_mode())
     return QTensor(data=data[:, :C], scale=scale[:, :C], tile=row_tile(3))
 
 
-@functools.partial(jax.jit, static_argnames=("expected_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("expected_m",))
 def grouped_gemm_nt_fp8_masked(qa: QTensor, qb: QTensor, masked_m: jax.Array,
-                               expected_m: int | None = None,
-                               interpret: bool | None = None):
+                               expected_m: int | None = None):
     """Masked NT (Wgrad) form: contraction (token) tiles beyond masked_m[e]
     are skipped — bitwise-invisible because dead token columns are zero."""
-    interpret = _interpret_default() if interpret is None else interpret
     C = qa.data.shape[2]
     if _use_padded(expected_m, C):
-        return grouped_gemm_nt_fp8(qa, qb, interpret=interpret)
+        return grouped_gemm_nt_fp8(qa, qb)
     return masked_grouped_gemm_nt_fp8_pallas(
         qa.data, qa.scale, qb.data, qb.scale, masked_m.astype(jnp.int32),
-        interpret=interpret)
+        interpret=interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("expected_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("expected_m",))
 def grouped_gemm_swiglu_quant_masked(qx: QTensor, qw13: QTensor,
                                      masked_m: jax.Array,
-                                     expected_m: int | None = None,
-                                     interpret: bool | None = None) -> QTensor:
+                                     expected_m: int | None = None) -> QTensor:
     """Masked grouped GEMM-1 with the fused SwiGLU + e4m3 re-quantize
     epilogue: (E, C, K) x (E, K, 2F) -> QTensor (E, C, F) row-tiled.  The
     bf16 island h never reaches HBM.  ``expected_m >= C`` does NOT fall back
     (the fusion is worth it at any load); masked_m = full C gives the padded
     bits anyway."""
-    interpret = _interpret_default() if interpret is None else interpret
     C = qx.data.shape[1]
     qx = _pad_q_axis(qx, 1, BM)
     data, scale = masked_grouped_gemm_swiglu_quant_pallas(
         qx.data, qx.scale, qw13.data, qw13.scale, masked_m.astype(jnp.int32),
-        interpret=interpret)
+        interpret=interpret_mode())
     return QTensor(data=data[:, :C], scale=scale[:, :C], tile=row_tile(3))

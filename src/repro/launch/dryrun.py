@@ -11,6 +11,9 @@ Usage:
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# a CPU lowering tool: pinned to the CPU backend, so on a machine with a TPU
+# it neither takes the chip nor fails for want of 512 of them
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse       # noqa: E402
 import json           # noqa: E402
@@ -19,7 +22,6 @@ import traceback      # noqa: E402
 
 import jax            # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from repro.compat import set_mesh  # noqa: E402
 
 from repro.configs import ARCH_IDS, get_arch  # noqa: E402
 from repro.configs.base import SHAPES, applicable_shapes  # noqa: E402
@@ -178,7 +180,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t0 = time.time()
     fn, args, meta, mesh = build_cell(arch, shape_name, multi_pod=multi_pod,
                                       recipe_name=recipe_name)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         compiled = lowered.compile()
     mem = compiled.memory_analysis()

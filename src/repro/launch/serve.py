@@ -9,20 +9,76 @@ token budget, interleaved bucketed prefill + masked full-batch decode in one
 jitted step, youngest-first eviction under page pressure.  The old
 fixed-batch shared-position loop lives on in serve/serve_step.py for the
 dry-run shape cells.
+
+Without --reduced the mesh spans the devices present and the parameters
+are placed by launch/sharding.py's specs; --reduced cuts every width to the
+CPU smoke size (configs/base.py).
 """
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_arch
-from repro.core.recipes import get_recipe
-from repro.launch.mesh import make_production_mesh, make_test_mesh
-from repro.launch.sharding import make_plan
-from repro.models.lm import ParallelPlan, init_params
+from repro.configs.base import ArchConfig
+from repro.core.recipes import Recipe, get_recipe
+from repro.launch import sharding
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_test_mesh
+from repro.models.lm import init_params
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.scheduler import Request
+
+
+def run(cfg: ArchConfig, *, recipe: Recipe, ecfg: ServeConfig, requests,
+        mesh=None, seed: int = 0, disagg=None, prefill_replicas: int = 1,
+        decode_replicas: int = 1, telemetry=None, log_fn=print):
+    """Serve ``requests`` to completion; returns (results, engine, params).
+
+    mesh=None builds one over the devices present (make_device_mesh).  The
+    parameters are initialised from ``seed`` straight into their
+    launch/sharding.py shardings; ``params`` is that unquantized tree (the
+    engine holds its own W8 copy when ``ecfg.w8_weights``).  ``disagg`` (a
+    serve.router.DisaggConfig) runs a prefill/decode fleet instead of one
+    engine; ``engine`` is then the first prefill replica."""
+    from repro.obs.sink import null_telemetry
+    tel = telemetry if telemetry is not None else null_telemetry()
+    if mesh is None:
+        mesh = make_device_mesh()
+    plan = sharding.make_plan(cfg, mesh)
+
+    def init(key):
+        return init_params(cfg, key)
+
+    key = jax.random.key(seed)
+    params_sh = sharding.tree_specs(cfg, mesh, jax.eval_shape(init, key))
+    params = jax.jit(init, out_shardings=params_sh)(key)
+    if disagg is not None:
+        from repro.serve.router import DisaggRouter
+        pes = [ServeEngine(cfg, recipe, plan, params,
+                           dataclasses.replace(ecfg, role="prefill"),
+                           telemetry=tel)
+               for _ in range(prefill_replicas)]
+        des = [ServeEngine(cfg, recipe, plan, params,
+                           dataclasses.replace(ecfg, role="decode"),
+                           telemetry=tel)
+               for _ in range(decode_replicas)]
+        runner = DisaggRouter(pes, des, dcfg=disagg, telemetry=tel)
+        engine = pes[0]
+        log_fn(f"[serve] disaggregated fleet: {len(pes)} prefill + "
+               f"{len(des)} decode replicas, transfer budget "
+               f"{disagg.transfer_budget_bytes / 2**20:.2f} MiB/cycle")
+    else:
+        engine = ServeEngine(cfg, recipe, plan, params, ecfg, telemetry=tel)
+        runner = engine
+    log_fn(f"[serve] {cfg.name} recipe={recipe.name} "
+           f"kv={'fp8' if ecfg.fp8_kv else 'bf16'} "
+           f"w8={ecfg.w8_weights} pool={engine.kv_bytes()/2**20:.1f} MiB "
+           f"mesh={dict(mesh.shape)}")
+    results = runner.run(requests, realtime=False)
+    return results, engine, params
 
 
 def main():
@@ -67,17 +123,13 @@ def main():
                          "the serve metrics registry at exit")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
+    mesh = None
     if args.reduced:
         cfg = cfg.reduced()
         mesh = make_test_mesh()
-        plan = ParallelPlan(mesh=mesh, dp_axes=("data",))
-    else:
-        mesh = make_production_mesh()
-        plan = make_plan(cfg, mesh)
-
     recipe = get_recipe(args.recipe)
-    params = init_params(cfg, jax.random.key(0))
     fp8 = recipe.name == "fp8_flow"
     ecfg = ServeConfig(
         max_batch=args.max_batch, page_size=args.page_size,
@@ -93,30 +145,10 @@ def main():
         tel = Telemetry(sinks=sinks)
     else:
         tel = null_telemetry()
+    disagg = None
     if args.disagg:
-        import dataclasses as _dc
-        from repro.serve.router import DisaggConfig, DisaggRouter
-        pes = [ServeEngine(cfg, recipe, plan, params,
-                           _dc.replace(ecfg, role="prefill", seed=ecfg.seed),
-                           telemetry=tel)
-               for _ in range(args.prefill_replicas)]
-        des = [ServeEngine(cfg, recipe, plan, params,
-                           _dc.replace(ecfg, role="decode", seed=ecfg.seed),
-                           telemetry=tel)
-               for _ in range(args.decode_replicas)]
-        runner = DisaggRouter(
-            pes, des, dcfg=DisaggConfig(
-                transfer_budget_bytes=args.transfer_budget), telemetry=tel)
-        engine = pes[0]
-        print(f"[serve] disaggregated fleet: {len(pes)} prefill + "
-              f"{len(des)} decode replicas, transfer budget "
-              f"{args.transfer_budget / 2**20:.2f} MiB/cycle")
-    else:
-        engine = ServeEngine(cfg, recipe, plan, params, ecfg, telemetry=tel)
-        runner = engine
-    print(f"[serve] {args.arch} recipe={recipe.name} "
-          f"kv={'fp8' if ecfg.fp8_kv else 'bf16'} "
-          f"w8={ecfg.w8_weights} pool={engine.kv_bytes()/2**20:.1f} MiB")
+        from repro.serve.router import DisaggConfig
+        disagg = DisaggConfig(transfer_budget_bytes=args.transfer_budget)
 
     r = np.random.default_rng(args.seed)
     reqs = [Request(prompt=list(r.integers(1, cfg.vocab,
@@ -125,7 +157,11 @@ def main():
                     temperature=args.temperature)
             for _ in range(args.requests)]
     t0 = time.perf_counter()
-    results = runner.run(reqs, realtime=False)
+    results, engine, _ = run(
+        cfg, recipe=recipe, ecfg=ecfg, requests=reqs, mesh=mesh,
+        seed=args.seed, disagg=disagg,
+        prefill_replicas=args.prefill_replicas,
+        decode_replicas=args.decode_replicas, telemetry=tel)
     dt = time.perf_counter() - t0
     n_tok = sum(len(v["tokens"]) for v in results.values())
     print(f"[serve] {len(results)}/{args.requests} requests, {n_tok} tokens "

@@ -7,6 +7,9 @@ fixes and only failed/missing cells re-run.
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# a CPU lowering tool: pinned to the CPU backend, so on a machine with a TPU
+# it neither takes the chip nor fails for want of 512 of them
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse   # noqa: E402
 import gc         # noqa: E402

@@ -5,9 +5,12 @@
       [--elastic] [--dist-wire fp8] [--dist-schedule stream] \
       [--remat-policy fp8_resident] [--grad-accum N]
 
-On a real TPU fleet this process runs once per host under
-`jax.distributed.initialize()`; on this container use --reduced for an
-executable configuration (full configs are exercised via launch.dryrun).
+Without --reduced the mesh spans the devices present: every chip on the
+'model' axis (expert parallel), or on the 'data' axis when the DP wire is
+on.  The train state is created already placed by launch/sharding.py's
+param_specs / opt_state_specs, and the unguarded step donates it.  On a
+fleet the process runs once per host under `jax.distributed.initialize()`.
+--reduced cuts every width to the CPU smoke size (configs/base.py).
 
 --dist-wire {off,fp8,bf16,f32} selects the explicit DP communication plan
 (repro.dist.DistPlan): quantized ZeRO-1 gradient reduce-scatter + FP8-split
@@ -38,22 +41,170 @@ and the loop runs the skip -> rollback -> bf16-demote recovery ladder.
 """
 import argparse
 import dataclasses
+import time
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_arch
-from repro.core.recipes import get_recipe
-from repro.data.pipeline import DataConfig
+from repro.configs.base import ArchConfig
+from repro.core.recipes import Recipe, get_recipe
+from repro.data.pipeline import DataConfig, make_batch
 from repro.dist import DistPlan
 from repro.dist.grad_comm import wire_grad_bytes
-from repro.launch.mesh import make_production_mesh, make_test_mesh
-from repro.launch.sharding import dist_state_specs, make_plan
-from repro.models.lm import ParallelPlan
-from repro.optim.adamw import AdamWConfig
+from repro.launch import sharding
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_test_mesh
+from repro.optim import adamw
 from repro.runtime.fault_tolerance import ElasticTrainer
 from repro.train.guards import GuardPlan, GuardPolicy
 from repro.train.loop import run as run_loop
 from repro.train.train_step import init_train_state, make_train_step
+
+
+def reduced_mesh(dist):
+    """The --reduced mesh: (1, 1), or DP-only over the visible devices when
+    the DP wire is on (its size must divide DistPlan.shard_multiple for
+    equal ZeRO shards)."""
+    if dist is None:
+        return make_test_mesh()
+    ndev = max(d for d in range(1, jax.device_count() + 1)
+               if dist.shard_multiple % d == 0
+               and jax.device_count() % d == 0)
+    if ndev < jax.device_count():
+        print(f"[train] WARNING: DP size clamped to {ndev} of "
+              f"{jax.device_count()} devices (must divide "
+              f"DistPlan.shard_multiple={dist.shard_multiple}); "
+              f"the rest sit idle")
+    return make_test_mesh((ndev, 1))
+
+
+def state_shardings(cfg: ArchConfig, mesh, state_shapes, dist):
+    """NamedShardings for the train state: params by param_specs, optimizer
+    state ZeRO-1 (opt_state_specs, or dist_state_specs for the DP wire's
+    flat buckets), the guard's EMA replicated."""
+    params_sh = sharding.tree_specs(cfg, mesh, state_shapes["params"])
+    if dist is not None:
+        opt_sh = sharding.dist_state_specs(mesh, state_shapes["opt"],
+                                           dist.axis)
+    else:
+        opt_sh = sharding.opt_state_specs(cfg, mesh, params_sh,
+                                          state_shapes["opt"])
+    out = {"params": params_sh, "opt": opt_sh}
+    if "guard" in state_shapes:
+        out["guard"] = jax.tree.map(lambda _: NamedSharding(mesh, P()),
+                                    state_shapes["guard"])
+    return out
+
+
+def run(cfg: ArchConfig, *, recipe: Recipe, steps: int, seq_len: int,
+        global_batch: int, lr: float = 1e-3, mesh=None, seed: int = 0,
+        dist=None, grad_accum: int = 1, guard=None, guard_policy=None,
+        ckpt_dir=None, elastic: bool = False, telemetry=None,
+        log_fn=print):
+    """Train ``cfg`` for ``steps`` steps; returns (state, history, info).
+
+    mesh=None builds one over the devices present (make_device_mesh).  The
+    state is initialised straight into its shardings; the step is compiled
+    ahead of the loop, so ``info['compile_s']`` is compile time apart from
+    the steps in ``history``.  Without a guard the step donates the state:
+    old and new state never coexist on the device.  The guarded loop keeps
+    the previous state alive for skip-step, so a guarded run holds two
+    copies of the state at the step boundary."""
+    if mesh is None:
+        mesh = make_device_mesh(model=1 if dist is not None else None)
+    plan = sharding.make_plan(cfg, mesh)
+    opt = adamw.AdamWConfig(lr=lr)
+    log_fn(f"[train] {cfg.name} ({cfg.n_params()/1e9:.2f}B params) "
+           f"recipe={recipe.name} pallas={recipe.use_pallas} "
+           f"mesh={dict(mesh.shape)}")
+
+    def init(key):
+        return init_train_state(cfg, opt, key, dist=dist, guard=guard)
+
+    key = jax.random.key(seed)
+    state_sh = state_shardings(cfg, mesh, jax.eval_shape(init, key), dist)
+    state = jax.jit(init, out_shardings=state_sh)(key)
+
+    if dist is not None:
+        from repro.dist import build_layout, streaming_fallback_reason
+        layout = build_layout(state["params"], dist)
+        n_dp = mesh.shape[dist.axis]
+        gbytes = wire_grad_bytes(cfg.n_params(), n_dp, dist.wire)
+        log_fn(f"[train] dist wire={dist.wire} schedule={dist.schedule} "
+               f"zero1 over '{dist.axis}' x{n_dp}: ~{gbytes/2**20:.0f} "
+               f"MiB grad bytes/step/device (bf16 all-reduce: "
+               f"{wire_grad_bytes(cfg.n_params(), n_dp, 'bf16', 'none')/2**20:.0f}"
+               f" MiB)")
+        if telemetry is not None:
+            # static wire accounting: one layout event + a modelled
+            # bytes/step counter the loop increments every step
+            telemetry.record("wire_layout", wire=dist.wire,
+                             schedule=dist.schedule, n_dp=n_dp,
+                             n_buckets=len(layout.buckets),
+                             n_sensitive=len(layout.sensitive),
+                             n_leaves=layout.n_leaves,
+                             fp8_elems=layout.fp8_elems,
+                             wire_rows=layout.wire_rows,
+                             grad_bytes_per_step=gbytes)
+            telemetry.per_step_counters["wire_grad_bytes_total"] = gbytes
+            telemetry.per_step_counters["wire_buckets_total"] = \
+                len(layout.buckets)
+        if dist.schedule == "stream":
+            # fast clear fallback: if the layout's buckets cannot align to
+            # layer boundaries (or the config cannot stream), warn and run
+            # post-hoc — the layered layout is kept, so the ZeRO-1 state
+            # stays valid
+            reason = streaming_fallback_reason(cfg, layout,
+                                               grad_accum=grad_accum)
+            if reason:
+                log_fn(f"[train] WARNING: streaming wire unavailable "
+                       f"({reason}); falling back to the post-hoc schedule")
+                dist = dataclasses.replace(dist, schedule="posthoc")
+
+    data = DataConfig(vocab=cfg.vocab, seq_len=seq_len,
+                      global_batch=global_batch, seed=seed)
+    with jax.set_mesh(mesh):        # the loop makes its batches under it too
+        batch0 = make_batch(data, 0)
+    if grad_accum > 1:
+        batch0 = jax.tree.map(
+            lambda a: a.reshape(grad_accum, a.shape[0] // grad_accum,
+                                *a.shape[1:]), batch0)
+    batch_sh = sharding.batch_specs(mesh, batch0, plan.dp_axes)
+
+    def build(rcp):
+        fn = make_train_step(cfg, rcp, plan, opt, dist=dist,
+                             grad_accum=grad_accum, total_steps=steps,
+                             warmup_steps=max(steps // 10, 1), guard=guard)
+        return jax.jit(fn, in_shardings=(state_sh, batch_sh),
+                       donate_argnums=() if guard is not None else (0,))
+
+    step = build(recipe)
+    fallback = None
+    if guard is not None and recipe.name != "bf16":
+        # graceful degradation target: same arch/plan/opt under the bf16
+        # recipe (no quantize sites), still guard-instrumented so the
+        # ladder keeps observing while demoted
+        fallback = build(get_recipe("bf16"))
+
+    t0 = time.perf_counter()
+    with jax.set_mesh(mesh):
+        step.lower(state, batch0).compile()
+    compile_s = time.perf_counter() - t0
+    log_fn(f"[train] compiled the step in {compile_s:.1f}s")
+
+    restore_sh = state_sh if (dist is not None and ckpt_dir is not None) \
+        else None
+    trainer = ElasticTrainer(n_data_shards=mesh.shape["data"]) \
+        if elastic else None
+    with jax.set_mesh(mesh):
+        state, hist = run_loop(step, state, data, n_steps=steps,
+                               grad_accum=grad_accum, ckpt_dir=ckpt_dir,
+                               elastic=trainer, restore_shardings=restore_sh,
+                               guard_policy=guard_policy,
+                               fallback_step=fallback, telemetry=telemetry,
+                               log_fn=log_fn)
+    return state, hist, {"compile_s": compile_s, "mesh": mesh}
 
 
 def main():
@@ -66,7 +217,6 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--elastic", action="store_true")
     ap.add_argument("--dist-wire", default="off",
                     choices=["off", "fp8", "bf16", "f32"],
@@ -110,12 +260,12 @@ def main():
                          "the metrics registry at exit")
     args = ap.parse_args()
 
-    from repro.obs.sink import JsonlSink, Telemetry, null_telemetry
+    enable_compile_cache()
+    from repro.obs.sink import JsonlSink, Telemetry
+    tel = None
     if args.obs_jsonl is not None or args.obs_prom is not None:
         sinks = (JsonlSink(args.obs_jsonl),) if args.obs_jsonl else ()
         tel = Telemetry(sinks=sinks)
-    else:
-        tel = null_telemetry()
 
     dist = DistPlan(wire=args.dist_wire, schedule=args.dist_schedule) \
         if args.dist_wire != "off" else None
@@ -124,105 +274,21 @@ def main():
         cfg = cfg.reduced()
     if args.remat_policy is not None:
         cfg = dataclasses.replace(cfg, remat_policy=args.remat_policy)
-    if args.reduced:
-        # DP size must divide DistPlan.shard_multiple for equal ZeRO shards
-        ndev = max(d for d in range(1, jax.device_count() + 1)
-                   if dist.shard_multiple % d == 0
-                   and jax.device_count() % d == 0) \
-            if dist is not None else 1
-        if dist is not None and ndev < jax.device_count():
-            print(f"[train] WARNING: DP size clamped to {ndev} of "
-                  f"{jax.device_count()} devices (must divide "
-                  f"DistPlan.shard_multiple={dist.shard_multiple}); "
-                  f"the rest sit idle")
-        mesh = make_test_mesh((ndev, 1))
-        plan = ParallelPlan(mesh=mesh, dp_axes=("data",))
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-        plan = make_plan(cfg, mesh)
-    print(f"[train] {args.arch} ({cfg.n_params()/1e9:.2f}B params) "
-          f"recipe={args.recipe} mesh={dict(mesh.shape)}")
-    if dist is not None:
-        n_dp = mesh.shape[dist.axis]
-        n = cfg.n_params()
-        print(f"[train] dist wire={dist.wire} schedule={dist.schedule} "
-              f"zero1 over '{dist.axis}' "
-              f"x{n_dp}: ~{wire_grad_bytes(n, n_dp, dist.wire)/2**20:.0f} "
-              f"MiB grad bytes/step/device "
-              f"(bf16 all-reduce: {wire_grad_bytes(n, n_dp, 'bf16', 'none')/2**20:.0f} MiB)")
-
-    recipe = get_recipe(args.recipe)
-    opt = AdamWConfig(lr=args.lr)
-    guard = GuardPlan(spike_factor=args.guard_spike_factor) \
-        if args.guard else None
-    state = init_train_state(cfg, opt, jax.random.key(0), dist=dist,
-                             guard=guard)
-    if dist is not None:
-        # static wire accounting: one layout event + a modelled bytes/step
-        # counter the loop increments every step
-        from repro.dist import build_layout
-        layout = build_layout(state["params"], dist)
-        n_dp = mesh.shape[dist.axis]
-        gbytes = wire_grad_bytes(cfg.n_params(), n_dp, dist.wire)
-        tel.record("wire_layout", wire=dist.wire, schedule=dist.schedule,
-                   n_dp=n_dp, n_buckets=len(layout.buckets),
-                   n_sensitive=len(layout.sensitive),
-                   n_leaves=layout.n_leaves, fp8_elems=layout.fp8_elems,
-                   wire_rows=layout.wire_rows,
-                   grad_bytes_per_step=gbytes)
-        tel.per_step_counters["wire_grad_bytes_total"] = gbytes
-        tel.per_step_counters["wire_buckets_total"] = len(layout.buckets)
-    if dist is not None and dist.schedule == "stream":
-        # fast clear fallback: if the layout's buckets cannot align to layer
-        # boundaries (or the config cannot stream), warn and run post-hoc —
-        # the layered layout is kept, so the ZeRO-1 state stays valid.
-        # (grad_accum no longer blocks streaming: microbatch grads
-        # accumulate locally and wire once on the last microbatch.)
-        from repro.dist import build_layout, streaming_fallback_reason
-        reason = streaming_fallback_reason(
-            cfg, build_layout(state["params"], dist),
-            grad_accum=args.grad_accum)
-        if reason:
-            print(f"[train] WARNING: streaming wire unavailable ({reason}); "
-                  f"falling back to the post-hoc schedule")
-            dist = dataclasses.replace(dist, schedule="posthoc")
-    step = jax.jit(make_train_step(cfg, recipe, plan, opt, dist=dist,
-                                   grad_accum=args.grad_accum,
-                                   total_steps=args.steps,
-                                   warmup_steps=max(args.steps // 10, 1),
-                                   guard=guard))
-    policy = fallback = None
-    if guard is not None:
+    guard = policy = None
+    if args.guard:
+        guard = GuardPlan(spike_factor=args.guard_spike_factor)
         policy = GuardPolicy(rollback_after=args.guard_rollback_after,
                              demote_steps=args.guard_demote_steps)
-        # graceful degradation target: same arch/plan/opt under the bf16
-        # recipe (no quantize sites), still guard-instrumented so the
-        # ladder keeps observing while demoted
-        if recipe.name != "bf16":
-            fallback = jax.jit(make_train_step(
-                cfg, get_recipe("bf16"), plan, opt, dist=dist,
-                grad_accum=args.grad_accum, total_steps=args.steps,
-                warmup_steps=max(args.steps // 10, 1), guard=guard))
         print(f"[train] guardrails armed: spike_factor="
               f"{args.guard_spike_factor} rollback_after="
               f"{args.guard_rollback_after} demote_steps="
               f"{args.guard_demote_steps}")
-    data = DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
-                      global_batch=args.global_batch)
-    elastic = ElasticTrainer(n_data_shards=mesh.shape["data"]) \
-        if args.elastic else None
-    restore_sh = None
-    if dist is not None and args.ckpt_dir is not None:
-        restore_sh = {"params": jax.tree.map(
-                          lambda _: None, state["params"]),
-                      "opt": dist_state_specs(mesh, state["opt"], dist.axis)}
-    with mesh:
-        state, hist = run_loop(step, state, data, n_steps=args.steps,
-                               grad_accum=args.grad_accum,
-                               ckpt_dir=args.ckpt_dir, elastic=elastic,
-                               restore_shardings=restore_sh,
-                               guard_policy=policy, fallback_step=fallback,
-                               telemetry=tel)
+    state, hist, _ = run(
+        cfg, recipe=get_recipe(args.recipe), steps=args.steps,
+        seq_len=args.seq_len, global_batch=args.global_batch, lr=args.lr,
+        mesh=reduced_mesh(dist) if args.reduced else None, dist=dist,
+        grad_accum=args.grad_accum, guard=guard, guard_policy=policy,
+        ckpt_dir=args.ckpt_dir, elastic=args.elastic, telemetry=tel)
     print(f"[train] done: loss {hist[0]['loss']:.4f} -> "
           f"{hist[-1]['loss']:.4f}")
     dev_ms = [h["device_ms"] for h in hist]

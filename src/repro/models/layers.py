@@ -121,6 +121,22 @@ def _constrain_carry(spec, qf, m0, l0, a0):
             jax.lax.with_sharding_constraint(a0, c5))
 
 
+def pvary_missing(x, axes):
+    """``x`` marked as varying over those manual mesh ``axes`` it does not
+    vary over yet (``jax.lax.pcast`` refuses an axis already varying)."""
+    vma = jax.typeof(x).vma
+    missing = tuple(a for a in axes if a not in vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
+def _vary_like(init, *refs):
+    """Mark a scan's initial carry as varying over every manual mesh axis
+    that ``refs`` (the body's inputs) vary over: inside ``shard_map`` the
+    carry that goes in must have the type of the one that comes out."""
+    vma = sorted(frozenset().union(*(jax.typeof(r).vma for r in refs)))
+    return jax.tree.map(lambda t: pvary_missing(t, vma), init)
+
+
 def _flash_fwd_impl(spec, q, k, v, q_pos, kv_pos):
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape
@@ -140,6 +156,7 @@ def _flash_fwd_impl(spec, q, k, v, q_pos, kv_pos):
     l0 = jnp.zeros((B, Sq, KV, G), jnp.float32)
     a0 = jnp.zeros((B, Sq, KV, G, hd), jnp.float32)
     qf, m0, l0, a0 = _constrain_carry(spec, qf, m0, l0, a0)
+    m0, l0, a0 = _vary_like((m0, l0, a0), qf, kb, vb, q_pos, pb)
 
     def body(carry, blk):
         m, l, acc = carry
@@ -195,7 +212,7 @@ def _flash_bwd(spec, res, g):
     # D_i = rowsum(g * out)
     Drow = jnp.sum(gf * out, axis=-1)                     # (B,Sq,KV,G)
 
-    dq0 = jnp.zeros_like(qf)
+    dq0 = _vary_like(jnp.zeros_like(qf), qf, gf, lse, Drow, kb, vb, q_pos, pb)
 
     def body(dq, blk):
         kblk, vblk, pblk = blk

@@ -27,7 +27,8 @@ from repro.core.moe import (DispatchPlan, MoEConfig, moe_block,
                             moe_block_decode, moe_block_decode_overlapped,
                             moe_block_overlapped, moe_block_tp)
 from repro.core.recipes import Recipe
-from repro.models.layers import apply_norm, attn_block, stage_ln_attn
+from repro.models.layers import (apply_norm, attn_block, pvary_missing,
+                                 stage_ln_attn)
 from repro.models.ssm import mamba2_block
 from repro.train.memory import MemoryPlan
 
@@ -230,7 +231,6 @@ def _mlp_stage(cfg, recipe, plan, p, x):
                       w13.reshape(D, g * F), w2)
         return y.reshape(B, S, D)
 
-    from repro.compat import pvary, shard_map
     tp_size = plan.mesh.shape[plan.tp_axis]
     use_tp = plan.mlp_tp and mlp_tp_ok(F, tp_size)
     gather = plan.fsdp_axis
@@ -253,8 +253,7 @@ def _mlp_stage(cfg, recipe, plan, p, x):
             # guard stats recorded inside this body are tracers of the
             # shard_map trace — thread them out per-shard, max-merge outside
             sv = quant_stats.drain_stats()
-            sv = pvary(sv, tuple(
-                a for a in all_axes if a not in getattr(sv, "vma", all_axes)))
+            sv = pvary_missing(sv, all_axes)
             return y, sv[None]
         return y
 
@@ -272,9 +271,9 @@ def _mlp_stage(cfg, recipe, plan, p, x):
         w13_spec = P(fs, None, None)
         w2_spec = P(None, fs)
     out_specs = (tok_spec, P(all_axes, None)) if armed else tok_spec
-    sm = shard_map(body, mesh=plan.mesh,
-                   in_specs=(tok_spec, w13_spec, w2_spec),
-                   out_specs=out_specs)
+    sm = jax.shard_map(body, mesh=plan.mesh,
+                       in_specs=(tok_spec, w13_spec, w2_spec),
+                       out_specs=out_specs)
     if armed:
         y, sv = sm(x, w13, w2)
         quant_stats.reinject_stats(jnp.max(sv, axis=0))
@@ -342,7 +341,6 @@ def _moe_stage(cfg, recipe, plan, p, x, decode=False):
                                {"w13": p["ws13"], "w2": p["ws2"]}, x)
         return y, jnp.mean(m["aux_loss"])
 
-    from repro.compat import shard_map
     gather = plan.fsdp_axis
     # decode-EP only exists when experts are EP-sharded; TP-experts (E < tp)
     # use the same TP block for decode (forward-only)
@@ -436,25 +434,22 @@ def _moe_stage(cfg, recipe, plan, p, x, decode=False):
         y, aux = body(x3.reshape(Bl * Sl, Dl), wr_l, we13_l, we2_l)
         # broadcast the aux scalar onto every mesh axis so one out_spec
         # (sharded over all axes) is valid in every mode/mesh
-        from repro.compat import pvary
-        aux = pvary(aux, tuple(
-            a for a in all_axes if a not in getattr(aux, "vma", all_axes)))
+        aux = pvary_missing(aux, all_axes)
         y = y.reshape(Bl, -1, Dl)
         if armed:
             # guard stats recorded inside this body are tracers of the
             # shard_map trace — thread them out per-shard, max-merge outside
             sv = quant_stats.drain_stats()
-            sv = pvary(sv, tuple(
-                a for a in all_axes if a not in getattr(sv, "vma", all_axes)))
+            sv = pvary_missing(sv, all_axes)
             return y, aux, sv[None]
         return y, aux
 
     out3 = (P(dp3, out_seq3, None), P(all_axes)) + \
         ((P(all_axes, None),) if armed else ())
-    sm = shard_map(body3, mesh=plan.mesh,
-                   in_specs=(P(dp3, seq3, None), P(None, None),
-                             we13_spec, we2_spec),
-                   out_specs=out3)
+    sm = jax.shard_map(body3, mesh=plan.mesh,
+                       in_specs=(P(dp3, seq3, None), P(None, None),
+                                 we13_spec, we2_spec),
+                       out_specs=out3)
 
     # Overlap lever (§dispatch pipeline): with moe_overlap set, the shared
     # expert — which depends only on x, never on the dispatch — is ISSUED

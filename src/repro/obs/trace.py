@@ -21,7 +21,6 @@ Two different clocks, two different tools:
 """
 from __future__ import annotations
 
-import contextlib
 import time
 
 import jax
@@ -31,10 +30,7 @@ STAGES = ("attn", "router", "dispatch", "expert", "combine")
 
 def annotate(name: str):
     """Device-side named scope (zero ops; trace-time metadata only)."""
-    try:
-        return jax.named_scope(name)
-    except Exception:                      # pragma: no cover - old jax
-        return contextlib.nullcontext()
+    return jax.named_scope(name)
 
 
 def stage_annotation(stage: str):
@@ -61,18 +57,14 @@ class Span:
         self._prof = None
 
     def __enter__(self):
-        try:
-            self._prof = jax.profiler.TraceAnnotation(f"host/{self.name}")
-            self._prof.__enter__()
-        except Exception:                  # pragma: no cover - no profiler
-            self._prof = None
+        self._prof = jax.profiler.TraceAnnotation(f"host/{self.name}")
+        self._prof.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.ms = (time.perf_counter() - self.t0) * 1e3
-        if self._prof is not None:
-            self._prof.__exit__(*exc)
+        self._prof.__exit__(*exc)
         if self.tel is not None:
             self.tel.histogram("span_ms", labels={"span": self.name}) \
                 .observe(self.ms)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.roofline import analysis
-from repro.compat import set_mesh
 
 
 def _cost_of(compiled) -> Dict[str, float]:
@@ -108,7 +107,7 @@ def probe_train(cfg, recipe, plan, mesh, params_shapes, B, S):
     pattern = cfg.pattern if len(cfg.pattern) == glen else (cfg.pattern[0],)
     fn = jax.jit(make_group_fn(pattern, cfg.moe),
                  in_shardings=(x_sh, _group_specs(cfg, mesh, slice_shapes)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = fn.lower(x_sds, slice_shapes).compile()
     _acc(total, _cost_of(comp), ng * cfg.grad_accum)
 
@@ -119,7 +118,7 @@ def probe_train(cfg, recipe, plan, mesh, params_shapes, B, S):
                                                "dense_layers")
         fn = jax.jit(make_group_fn((cfg.pattern[0],) * glen_d, False),
                      in_shardings=(x_sh, _group_specs(cfg, mesh, sl)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = fn.lower(x_sds, sl).compile()
         _acc(total, _cost_of(comp), ng_d * cfg.grad_accum)
 
@@ -129,7 +128,7 @@ def probe_train(cfg, recipe, plan, mesh, params_shapes, B, S):
                                                "enc_layers")
         fn = jax.jit(make_group_fn(("global",) * glen_e, False),
                      in_shardings=(x_sh, _group_specs(cfg, mesh, sl)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = fn.lower(x_sds, sl).compile()
         _acc(total, _cost_of(comp), ng_e * cfg.grad_accum)
 
@@ -176,7 +175,7 @@ def _probe_head(cfg, recipe, plan, mesh, params_shapes, B, S, total, *,
     x_sds = jax.ShapeDtypeStruct((B, S, D), jnp.bfloat16)
     t_sds = jax.ShapeDtypeStruct((B, S), jnp.int32)
     fn = jax.jit(g, in_shardings=(x_sh, sub_specs, tok_sh, tok_sh))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = fn.lower(x_sds, sub, t_sds, t_sds).compile()
     return _acc(total, _cost_of(comp), mult)
 
@@ -204,7 +203,7 @@ def _probe_opt(cfg, mesh, params_shapes, total):
         return adamw.apply_updates(opt, params, grads, state)[:2]
 
     fn = jax.jit(f, in_shardings=(p_specs, p_specs, o_specs))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = fn.lower(params_shapes, g_shapes, opt_shapes).compile()
     return _acc(total, _cost_of(comp), 1)
 
@@ -235,7 +234,7 @@ def probe_infer(cfg, recipe, plan, mesh, params_shapes, B, S, *, decode):
         pattern = cfg.pattern if len(cfg.pattern) == glen else (cfg.pattern[0],)
         fn = jax.jit(make_fwd(pattern, cfg.moe),
                      in_shardings=(x_sh, _group_specs(cfg, mesh, sl)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = fn.lower(x_sds, sl).compile()
         _acc(total, _cost_of(comp), ng)
         nd = cfg.n_dense_layers if cfg.moe else 0
@@ -244,7 +243,7 @@ def probe_infer(cfg, recipe, plan, mesh, params_shapes, B, S, *, decode):
                                                    "dense_layers")
             fn = jax.jit(make_fwd((cfg.pattern[0],) * glen_d, False),
                          in_shardings=(x_sh, _group_specs(cfg, mesh, sl)))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = fn.lower(x_sds, sl).compile()
             _acc(total, _cost_of(comp), ng_d)
         if cfg.encdec:
@@ -252,7 +251,7 @@ def probe_infer(cfg, recipe, plan, mesh, params_shapes, B, S, *, decode):
                                                    "enc_layers")
             fn = jax.jit(make_fwd(("global",) * glen_e, False),
                          in_shardings=(x_sh, _group_specs(cfg, mesh, sl)))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = fn.lower(x_sds, sl).compile()
             _acc(total, _cost_of(comp), ng_e)
         return _probe_head(cfg, recipe, plan, mesh, params_shapes, B, S,
@@ -339,7 +338,7 @@ def probe_infer(cfg, recipe, plan, mesh, params_shapes, B, S, *, decode):
     in_sh += [cspec["k"], cspec["v"], sspec["state"], sspec["conv"],
               NamedSharding(mesh, P())]
     fn = jax.jit(grp, in_shardings=tuple(in_sh))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = fn.lower(*args).compile()
     _acc(total, _cost_of(comp), ng)
     return _probe_head(cfg, recipe, plan, mesh, params_shapes, B, 1, total,

@@ -21,7 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.fp8 import TILE, E4M3_MAX
 from repro.core.quant import quantize_rowwise, _dequantize_nocount
 
@@ -51,7 +50,7 @@ def compressed_psum(x, axis_name: str):
     reduce_scatter(e4m3) -> local dequant+sum in f32 -> all_gather(e4m3).
     Byte cost: 2 x (N/P x 1B + scales) per hop instead of 2 x N x 4B."""
     q, n, pad, = _q_flat(x)
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     rows = q.data.shape[0]
     rpad = (-rows) % P
     if rpad:

@@ -174,6 +174,8 @@ def run(train_step: Callable, state, data_cfg: DataConfig, *,
         dt = time.monotonic() - t0
         history.append({"step": step, "loss": loss, "dt": dt,
                         "device_ms": sp_dev.ms, "fetch_ms": sp_fetch.ms})
+        if "grad_norm" in host_metrics:
+            history[-1]["grad_norm"] = float(host_metrics["grad_norm"])
 
         if tel.enabled:
             values = {"loss": loss}

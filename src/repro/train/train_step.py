@@ -340,7 +340,6 @@ def _make_dist_train_step(cfg: ArchConfig, recipe: Recipe, plan: ParallelPlan,
                           opt: adamw.AdamWConfig, dist, *, grad_accum: int,
                           total_steps: int, warmup_steps: int, guard=None):
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.dist import grad_comm
     from repro.dist import opt_state as ost
     from repro.dist.plan import (bucket_flat, bucket_scatter, build_layout,
@@ -541,13 +540,17 @@ def _make_dist_train_step(cfg: ArchConfig, recipe: Recipe, plan: ParallelPlan,
         opt_in = {"step": P(),
                   "flat": tuple(P(axis, None) for _ in layout.buckets),
                   "sens": P()}
+        # check_vma=False: the body differentiates the replicated params
+        # into per-replica gradients and reduces them on its own wire.  With
+        # varying-axes checking, JAX would psum those gradients implicitly
+        # (the transpose of replicating an invariant input) before the wire.
         if guard is None:
             def body(params, opt_st, batch):
                 return body_impl(params, opt_st, batch, None)
 
-            sm = shard_map(body, mesh=mesh,
-                           in_specs=(P(), opt_in, batch_specs),
-                           out_specs=(P(), opt_in, P()))
+            sm = jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(), opt_in, batch_specs),
+                               out_specs=(P(), opt_in, P()), check_vma=False)
             new_params, new_opt, metrics = sm(params, state["opt"], batch)
             return {"params": new_params, "opt": new_opt}, metrics
 
@@ -555,9 +558,9 @@ def _make_dist_train_step(cfg: ArchConfig, recipe: Recipe, plan: ParallelPlan,
             with quant.collect_stats():
                 return body_impl(params, opt_st, batch, gstate)
 
-        sm = shard_map(body, mesh=mesh,
-                       in_specs=(P(), opt_in, batch_specs, P()),
-                       out_specs=(P(), opt_in, P(), P()))
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(), opt_in, batch_specs, P()),
+                           out_specs=(P(), opt_in, P(), P()), check_vma=False)
         new_params, new_opt, metrics, new_g = sm(
             params, state["opt"], batch, state["guard"])
         return {"params": new_params, "opt": new_opt, "guard": new_g}, \

@@ -10,5 +10,5 @@ def rng():
 
 
 def make_mesh11():
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     return make_mesh((1, 1), ("data", "model"))

@@ -8,7 +8,6 @@ import pytest
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.core import casts
 from repro.core.linear import expert_ffn, quantize_entry
@@ -67,13 +66,15 @@ def test_moe_block_cast_count(name):
         return jax.lax.psum(jnp.sum(y.astype(jnp.float32) ** 2),
                             ("data", "model"))
 
-    sm = shard_map(body, mesh=mesh,
-                   in_specs=(P(("data", "model"), None), P(None, None),
-                             P("model", None, None), P("model", None, None)),
-                   out_specs=P())
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(("data", "model"), None), P(None, None),
+                                 P("model", None, None), P("model", None, None)),
+                       out_specs=P())
+    # the ledger counts trace-time casts: tracing the gradient once is
+    # enough, and running shard_map eagerly would take a minute
     with casts.ledger() as led:
-        jax.grad(lambda *a: jnp.sum(sm(*a)), argnums=(0, 1, 2, 3))(
-            x, wr, w13, w2)
+        jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(sm(*a)),
+                                argnums=(0, 1, 2, 3)))(x, wr, w13, w2)
     assert led.activation_casts() == EXPECTED_MOE[name], led.summary()
 
 
@@ -147,7 +148,7 @@ def test_staged_streaming_backward_stays_two_casts():
     more by the remat recompute trace) and ONE backward island quantize —
     no new explicit cast sites, no explicit dequantize; every wire/state
     quantize stays fused-kind."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_arch
     from repro.data.pipeline import DataConfig, make_batch
     from repro.dist import DistPlan
@@ -191,7 +192,7 @@ def test_streamed_casts_under_every_remat_policy(policy):
     quantize per forward trace (plus one per remat retrace when a policy
     checkpoints), and no new explicit cast tags."""
     import dataclasses
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_arch
     from repro.data.pipeline import DataConfig, make_batch
     from repro.dist import DistPlan

@@ -13,7 +13,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import checkpointing
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.configs import get_arch
 from repro.core import casts
 from repro.core.fp8 import TILE, is_po2
@@ -114,9 +114,9 @@ def test_scale_agreement_identical_buckets(rng):
         pay, exp = grad_comm.quantize_bucket(g[0], "data")
         return pay[None], exp[None]
 
-    f = shard_map(quant, mesh=mesh,
-                  in_specs=P("data", None, None),
-                  out_specs=(P("data", None, None), P("data", None, None)))
+    f = jax.shard_map(quant, mesh=mesh,
+                      in_specs=P("data", None, None),
+                      out_specs=(P("data", None, None), P("data", None, None)))
     # different grads per replica -> exponents still agree everywhere
     g_diff = jnp.asarray(rng.normal(size=(n, rows, TILE)) *
                          (10.0 ** rng.integers(-3, 3, (n, 1, 1))),
@@ -145,8 +145,8 @@ def test_reduce_scatter_matches_mean(rng, wire):
     def red(gl):
         return grad_comm.reduce_scatter_bucket(gl[0], "data", n, wire)
 
-    f = shard_map(red, mesh=mesh, in_specs=P("data", None, None),
-                  out_specs=P("data", None))
+    f = jax.shard_map(red, mesh=mesh, in_specs=P("data", None, None),
+                      out_specs=P("data", None))
     got = np.asarray(f(g))                       # (rows, TILE) re-stitched
     want = np.asarray(g).mean(axis=0)
     if wire == "f32":

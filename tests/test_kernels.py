@@ -7,7 +7,7 @@ import pytest
 
 from repro.core.fp8 import TILE
 from repro.core.quant import QTensor, quantize, _dequantize_nocount
-from repro.kernels import ops, ref
+from repro.kernels import interpret_mode, ops, ref
 
 
 def _bits(a):
@@ -225,14 +225,16 @@ def test_masked_oracles_tile_granular():
     E, C, K, N = 2, 256, 256, 128
     qx, qw = _gg_operands(seed=31, E=E, C=C, K=K, N=N)
     mm = jnp.asarray([37, 200], jnp.int32)      # mid-tile counts, garbage beyond
+    interp = interpret_mode()
     out_m = masked_grouped_gemm_fp8_pallas(qx.data, qx.scale, qw.data,
-                                           qw.scale, mm)
+                                           qw.scale, mm, interpret=interp)
     out_r = ref.masked_grouped_gemm_fp8_ref(qx.data, qx.scale, qw.data,
                                             qw.scale, mm)
     assert np.array_equal(np.asarray(out_m).view(np.uint16),
                           np.asarray(out_r).view(np.uint16))
     d_m, s_m = masked_grouped_gemm_fp8_pallas(qx.data, qx.scale, qw.data,
-                                              qw.scale, mm, quant_out=True)
+                                              qw.scale, mm, quant_out=True,
+                                              interpret=interp)
     d_r, s_r = ref.masked_grouped_gemm_fp8_quant_out_ref(
         qx.data, qx.scale, qw.data, qw.scale, mm)
     assert np.array_equal(_bits(d_m), _bits(d_r))
@@ -241,7 +243,7 @@ def test_masked_oracles_tile_granular():
     w13 = _x(33, E, K, 2 * N, spread=0.3) * 0.05
     qw13 = quantize(w13, (1, TILE, TILE), tag="t")
     d_f, s_f = masked_grouped_gemm_swiglu_quant_pallas(
-        qx.data, qx.scale, qw13.data, qw13.scale, mm)
+        qx.data, qx.scale, qw13.data, qw13.scale, mm, interpret=interp)
     d_fr, s_fr = ref.masked_grouped_gemm_swiglu_quant_ref(
         qx.data, qx.scale, qw13.data, qw13.scale, mm)
     assert np.array_equal(_bits(d_f), _bits(d_fr))
@@ -250,7 +252,7 @@ def test_masked_oracles_tile_granular():
     qa = quantize(_x(34, E, 128, C, spread=0.5), (1, 1, TILE), tag="t")
     qb = quantize(_x(35, E, 128, C, spread=0.5) * 0.1, (1, 1, TILE), tag="t")
     nt_m = masked_grouped_gemm_nt_fp8_pallas(qa.data, qa.scale, qb.data,
-                                             qb.scale, mm)
+                                             qb.scale, mm, interpret=interp)
     nt_r = ref.masked_grouped_gemm_nt_fp8_ref(qa.data, qa.scale, qb.data,
                                               qb.scale, mm)
     assert np.array_equal(np.asarray(nt_m).view(np.uint32),

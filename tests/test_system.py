@@ -8,8 +8,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.moe import MoEConfig, moe_block, _dispatch_plan, _expert_plan
 from repro.core.recipes import get_recipe
 from tests.conftest import make_mesh11
@@ -73,10 +71,10 @@ def test_moe_block_output_is_weighted_expert_mix():
         y, m = moe_block(recipe, cfg, x, wr, w13, w2)
         return y
 
-    sm = shard_map(body, mesh=mesh,
-                   in_specs=(P(("data", "model"), None), P(None, None),
-                             P("model", None, None), P("model", None, None)),
-                   out_specs=P(("data", "model"), None))
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(("data", "model"), None), P(None, None),
+                                 P("model", None, None), P("model", None, None)),
+                       out_specs=P(("data", "model"), None))
     with mesh:
         y = sm(x, wr, w13, w2)
 
@@ -114,10 +112,10 @@ def test_fp8_dispatch_payload_is_one_byte():
             y, _ = moe_block(recipe, cfg, x, wr, w13, w2)
             return y
 
-        sm = shard_map(body, mesh=mesh,
-                       in_specs=(P(("data", "model"), None), wr_s, w13_s,
-                                 w2_s),
-                       out_specs=P(("data", "model"), None))
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(("data", "model"), None), wr_s, w13_s,
+                                     w2_s),
+                           out_specs=P(("data", "model"), None))
         args = [jax.ShapeDtypeStruct((T, D), jnp.bfloat16),
                 jax.ShapeDtypeStruct((D, E), jnp.float32),
                 jax.ShapeDtypeStruct((E, D, 2 * F), jnp.float32),
