@@ -15,8 +15,13 @@ def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
-    there and nothing is changed.  Otherwise the cache goes to
-    ``.jax_cache/`` at the checkout root.  Call before the first compile."""
+    there and its place is not changed.  Otherwise the cache goes to
+    ``.jax_cache/`` at the checkout root.  Either way a program's metadata
+    (its ``named_scope`` op names) is part of its key: without it, two
+    programs that differ only in their scopes share one entry, and the one
+    loaded second reports the other's op names to the profiler and in its
+    HLO text.  Call before the first compile."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

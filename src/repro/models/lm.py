@@ -1099,12 +1099,52 @@ def _run_paged_stack(cfg, recipe, plan, stack_params, stack_kinds, moe, x,
     unwritten/scratch rows out of every receptive field).
     Returns (x, new_pool)."""
     from repro.models.layers import flash_attention, project_qkv
+    from repro.obs.trace import stage_annotation
     from repro.serve.paged_kv import page_read, page_write_rows
 
     n = len(stack_kinds)
     pat = cfg.pattern if n % len(cfg.pattern) == 0 else (stack_kinds[0],)
     glen = len(pat)
     ng = n // glen
+
+    def attend(xc, pi, kc, vc, window):
+        """ln1 -> q/k/v -> this step's rows into the pages -> attention
+        (over the paged history for decode and continuations) -> wo."""
+        h = apply_norm(cfg.norm, xc, pi, "ln1")
+        q, k, v = project_qkv(cfg, pi, h, positions)
+        rows_k = k[:, 0] if decode else k[0]
+        rows_v = v[:, 0] if decode else v[0]
+        kc = page_write_rows(kc, rows_k, page_idx, slot_idx)
+        vc = page_write_rows(vc, rows_v, page_idx, slot_idx)
+        if decode:
+            from repro.models.layers import decode_attention
+            kd = page_read(kc, page_tables, jnp.bfloat16)
+            vd = page_read(vc, page_tables, jnp.bfloat16)
+            o = decode_attention(q, kd.astype(q.dtype),
+                                 vd.astype(q.dtype), pos=pos,
+                                 window=window, softcap=cfg.attn_softcap)
+        elif history:
+            # chunked-prefill continuation: attend over the request's
+            # full paged history (this chunk's rows included — they were
+            # just written above) with absolute-position causal masking
+            kd = page_read(kc, page_tables, jnp.bfloat16)
+            vd = page_read(vc, page_tables, jnp.bfloat16)
+            Skv = kd.shape[1]
+            bk = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
+                      if Skv % b == 0)
+            o = flash_attention(q, kd.astype(q.dtype), vd.astype(q.dtype),
+                                q_pos=positions,
+                                kv_pos=jnp.arange(Skv, dtype=jnp.int32),
+                                causal=True, window=window,
+                                softcap=cfg.attn_softcap, block_k=bk)
+        else:
+            o = flash_attention(q, k, v, q_pos=positions,
+                                kv_pos=positions, causal=True,
+                                window=window, softcap=cfg.attn_softcap)
+        B, S = xc.shape[:2]
+        mix = jnp.einsum("bsn,nd->bsd", o.reshape(B, S, -1),
+                         pi["wo"].astype(xc.dtype))
+        return xc + mix, kc, vc
 
     def body(xc, xs):
         new_pools = []
@@ -1113,47 +1153,15 @@ def _run_paged_stack(cfg, recipe, plan, stack_params, stack_kinds, moe, x,
             kc = jax.tree.map(lambda a: a[i], xs["k"])
             vc = jax.tree.map(lambda a: a[i], xs["v"])
             window = cfg.window if pat[i] == "local" else 0
-            h = apply_norm(cfg.norm, xc, pi, "ln1")
-            q, k, v = project_qkv(cfg, pi, h, positions)
-            rows_k = k[:, 0] if decode else k[0]
-            rows_v = v[:, 0] if decode else v[0]
-            kc = page_write_rows(kc, rows_k, page_idx, slot_idx)
-            vc = page_write_rows(vc, rows_v, page_idx, slot_idx)
-            if decode:
-                from repro.models.layers import decode_attention
-                kd = page_read(kc, page_tables, jnp.bfloat16)
-                vd = page_read(vc, page_tables, jnp.bfloat16)
-                o = decode_attention(q, kd.astype(q.dtype),
-                                     vd.astype(q.dtype), pos=pos,
-                                     window=window, softcap=cfg.attn_softcap)
-            elif history:
-                # chunked-prefill continuation: attend over the request's
-                # full paged history (this chunk's rows included — they were
-                # just written above) with absolute-position causal masking
-                kd = page_read(kc, page_tables, jnp.bfloat16)
-                vd = page_read(vc, page_tables, jnp.bfloat16)
-                Skv = kd.shape[1]
-                bk = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
-                          if Skv % b == 0)
-                o = flash_attention(q, kd.astype(q.dtype), vd.astype(q.dtype),
-                                    q_pos=positions,
-                                    kv_pos=jnp.arange(Skv, dtype=jnp.int32),
-                                    causal=True, window=window,
-                                    softcap=cfg.attn_softcap, block_k=bk)
-            else:
-                o = flash_attention(q, k, v, q_pos=positions,
-                                    kv_pos=positions, causal=True,
-                                    window=window, softcap=cfg.attn_softcap)
-            B, S = xc.shape[:2]
-            mix = jnp.einsum("bsn,nd->bsd", o.reshape(B, S, -1),
-                             pi["wo"].astype(xc.dtype))
-            xc = xc + mix
+            with stage_annotation("attn"):
+                xc, kc, vc = attend(xc, pi, kc, vc, window)
             h2 = apply_norm(cfg.norm, xc, pi, "ln2")
             if moe:
                 mo, _ = _moe_stage(cfg, recipe, plan, pi, h2, decode=decode)
             else:
-                mo = _mlp_decode(cfg, pi, h2) if decode \
-                    else _mlp_stage(cfg, recipe, plan, pi, h2)
+                with stage_annotation("ffn"):
+                    mo = _mlp_decode(cfg, pi, h2) if decode \
+                        else _mlp_stage(cfg, recipe, plan, pi, h2)
             xc = xc + mo
             new_pools.append({"k": kc, "v": vc})
         emit = jax.tree.map(lambda *ys: jnp.stack(ys), *new_pools)

@@ -13,6 +13,8 @@ Renders, for whatever record kinds the stream contains:
     will consume);
   * cast-ledger snapshots — activation-cast counts per traced program;
   * serve summary — tick counters, KV-pool occupancy, TTFT/TBT stats;
+  * serve spans — each engine tick split into host work and the wait on
+    the chip, recompiled ticks, queue wait and prefill per request;
   * benchmark records — the unified benchmarks/common.py emit() stream.
 """
 from __future__ import annotations
@@ -21,6 +23,8 @@ import argparse
 import json
 import sys
 from typing import Dict, List
+
+from repro.obs import trace
 
 
 def load_records(paths) -> List[dict]:
@@ -163,8 +167,9 @@ def render_serve(kinds, out):
             out(f"  prompt tokens served from prefix cache: {cached}")
     for r in summ:
         c = {k: v for k, v in r.items() if k not in ("t", "kind")}
-        out("  totals: " + " ".join(f"{k}={int(v)}"
-                                    for k, v in sorted(c.items())))
+        out("  totals: " + " ".join(
+            f"{k}={int(v) if isinstance(v, (int, float)) else v}"
+            for k, v in sorted(c.items())))
     hits = kinds.get("prefix_hit", [])
     if hits:
         toks = [r.get("cached_tokens", 0) for r in hits]
@@ -188,6 +193,67 @@ def render_serve(kinds, out):
         c = {k: v for k, v in r.items() if k not in ("t", "kind")}
         out("  fleet totals: " + " ".join(f"{k}={int(v)}"
                                           for k, v in sorted(c.items())))
+
+
+TICK_PHASES = ("serve/schedule", "serve/inputs", "serve/dispatch",
+               "serve/wait", "serve/emit")
+
+
+def render_spans(spans, out):
+    """The engine's `span` records: each serve/tick split into its phases
+    (host = tick - serve/wait, the host blocked on the chip), apart for
+    ticks that ran a prefill chunk and decode-only ticks, the ticks whose
+    step compiled, and per request from the marks: queue wait (first
+    admit - arrival), prefill (first token - first admit) and decode
+    (finish - first token)."""
+    ticks = [r for r in spans if r.get("name") == "serve/tick"]
+    kids: Dict[int, Dict[str, float]] = {}
+    for r in spans:
+        if r.get("name") in TICK_PHASES and r.get("parent") is not None:
+            ph = kids.setdefault(r["parent"], {})
+            ph[r["name"]] = ph.get(r["name"], 0.0) + r["dur_ns"] * 1e-6
+    ran = [r for r in ticks if (r.get("attrs") or {}).get("ran")]
+    out(f"== serve spans: {len(ticks)} ticks ({len(ran)} ran work) ==")
+
+    def split(rows, label):
+        tick_ms = [r["dur_ns"] * 1e-6 for r in rows]
+        wait = [kids.get(r["id"], {}).get("serve/wait", 0.0) for r in rows]
+        out(f"  {label:<11s} {_fmt_ms(_stats(tick_ms))}")
+        out(f"  {'host':<11s} "
+            f"{_fmt_ms(_stats([t - w for t, w in zip(tick_ms, wait)]))}")
+
+    if ran:
+        split(ran, "tick")
+        for ph in TICK_PHASES:
+            vals = [kids.get(r["id"], {}).get(ph, 0.0) for r in ran]
+            out(f"  {ph[6:]:<11s} {_fmt_ms(_stats(vals))}")
+        chunked = [r for r in ran if r["attrs"].get("chunk")]
+        for rows, what in ((chunked, "with a prefill chunk"),
+                           ([r for r in ran if not r["attrs"].get("chunk")],
+                            "decode only")):
+            if rows:
+                out(f"  -- {len(rows)} ticks {what}:")
+                split(rows, "tick")
+        if chunked:
+            toks = [float(r["attrs"]["chunk"]) for r in chunked]
+            s = _stats(toks)
+            out(f"  prompt tokens per chunk: mean={s['mean']:.1f} "
+                f"max={s['max']:.0f}")
+    comp = [r for r in ticks if (r.get("attrs") or {}).get("compiled")]
+    if comp:
+        shapes = sorted({(a.get("bucket"), a.get("history"),
+                          a.get("n_decode", 0) > 0)
+                         for a in (r["attrs"] for r in comp)}, key=str)
+        out(f"  recompiled ticks: {len(comp)} "
+            f"(bucket, history, decode): {shapes}")
+    first = trace.first_marks((r["name"], r.get("attrs") or {})
+                              for r in spans if r.get("dur_ns") is None)
+    for label, a, b in (("queue wait", "serve/submit", "serve/admit"),
+                        ("prefill", "serve/admit", "serve/first_token"),
+                        ("decode", "serve/first_token", "serve/finish")):
+        xs = [(f[b] - f[a]) * 1e3 for f in first.values() if a in f and b in f]
+        if xs:
+            out(f"  {label:<11s} {_fmt_ms(_stats(xs))}")
 
 
 def render_disagg(kinds, out):
@@ -258,6 +324,8 @@ def render(recs, out=print) -> int:
                                 "serve_summary", "prefix_hit", "route",
                                 "router_summary")):
         render_serve(kinds, out)
+    if "span" in kinds:
+        render_spans(kinds["span"], out)
     if any(k in kinds for k in ("migration", "disagg_summary")):
         render_disagg(kinds, out)
     if "bench" in kinds:
@@ -266,7 +334,7 @@ def render(recs, out=print) -> int:
              ("step", "guard", "cast_ledger", "wire_layout", "serve_tick",
               "request_done", "serve_summary", "prefix_hit", "route",
               "router_summary", "migration", "disagg_summary", "bench",
-              "registry")]
+              "registry", "span")]
     if other:
         out("== other records ==")
         for k in sorted(other):

@@ -9,6 +9,10 @@ seconds, "kind": <event kind>}.  Three sinks:
   MemorySink    bounded in-memory ring (tests, and the reporter's live use).
   NullSink      swallows everything (telemetry off).
 
+`Telemetry.flush()` also writes the records that the process-wide span
+ring (obs/trace.py) gained since its last flush, each once, as `span`
+records: the engine's tick phases and request marks reach `--obs-jsonl`.
+
 The Telemetry facade binds a Registry + sinks + the legacy human log_fn:
 typed events replace the loop's former unstructured f-strings — each
 `tel.event(kind, msg=..., **fields)` writes the structured record to the
@@ -22,6 +26,7 @@ import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
 
+from repro.obs import trace
 from repro.obs.metrics import MS_BUCKETS, Registry
 
 
@@ -112,6 +117,8 @@ class Telemetry:
         # static per-step counter increments (e.g. the modelled DP-wire
         # bytes/step a launcher registers once from the GradLayout)
         self.per_step_counters: Dict[str, float] = {}
+        # the first span-ring record flush() has not written yet
+        self._span_seq = trace.RING.total
 
     # -- events ------------------------------------------------------------
     def event(self, kind: str, msg: Optional[str] = None, **fields) -> dict:
@@ -142,10 +149,6 @@ class Telemetry:
     def histogram(self, name, edges=MS_BUCKETS, labels=None):
         return self.registry.histogram(name, edges, labels)
 
-    def span(self, name: str):
-        from repro.obs.trace import Span
-        return Span(self, name)
-
     def step(self, step: int, values: Dict[str, float],
              spans: Optional[Dict[str, float]] = None,
              extra: Optional[dict] = None) -> None:
@@ -174,6 +177,19 @@ class Telemetry:
             f.write(self.registry.to_prometheus())
 
     def flush(self) -> None:
+        """Write the span ring's records not written yet (each once) as
+        `span` records, then flush the sinks."""
+        if self.enabled:
+            recs = trace.RING.records(self._span_seq)
+            if recs:
+                self._span_seq = recs[-1].seq + 1
+                now_ns, now = time.perf_counter_ns(), self.clock()
+                for r in recs:
+                    self.sink.emit({
+                        "t": now - (now_ns - r.start_ns) * 1e-9,
+                        "kind": "span", "name": r.name, "id": r.id,
+                        "parent": r.parent, "start_ns": r.start_ns,
+                        "dur_ns": r.dur_ns, "attrs": r.attrs})
         self.sink.flush()
 
     def close(self) -> None:

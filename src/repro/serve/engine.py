@@ -51,6 +51,7 @@ from repro.core.recipes import Recipe
 from repro.models.lm import (ParallelPlan, paged_decode_step, paged_prefill)
 from repro.obs.metrics import po2_buckets
 from repro.obs.sink import null_telemetry
+from repro.obs.trace import Span, mark
 from repro.serve.paged_kv import (PageAllocator, copy_page, init_paged_cache,
                                   pool_nbytes)
 from repro.serve.scheduler import Request, RequestState, Scheduler
@@ -210,6 +211,7 @@ class ServeEngine:
         # to a decode replica and the receiver acks
         self.handoff: deque = deque()
         self._codec = None
+        self._tick_now = self._tick_t0 = 0.0     # see _clock
 
     @property
     def codec(self):
@@ -249,6 +251,7 @@ class ServeEngine:
         if self.alloc.pages_for(P + req.max_new_tokens) > ecfg.n_pages - 1:
             self._reject(req, "request alone exceeds the KV pool")
         self.sched.submit(req)
+        mark("serve/submit", rid=req.rid, arrival=req.arrival_time)
 
     # -- one tick ----------------------------------------------------------
     def _alloc_pages(self, n: int):
@@ -281,9 +284,28 @@ class ServeEngine:
         return st.slot in self.sched.active
 
     def tick(self, now: float, results: Dict[int, dict]) -> bool:
-        """One engine tick; returns True if any work ran."""
-        ecfg, sched = self.ecfg, self.sched
+        """One engine tick; returns True if any work ran.
 
+        `now` is the caller's request clock (the one `arrival_time` is on).
+        Each call closes one `serve/tick` span whose children are the
+        tick's phases; tokens are stamped at delivery, `now` plus the host
+        time spent in the tick so far (`_clock`)."""
+        self._tick_now, self._tick_t0 = now, time.perf_counter()
+        with Span("serve/tick", ran=False, n_decode=0, bucket=None,
+                  history=False, chunk=0, rid=None, n_waiting=0,
+                  compiled=False) as sp:
+            sp.attrs["ran"] = ran = self._tick(results, sp.attrs)
+        return ran
+
+    def _clock(self) -> float:
+        """The request clock now, inside a tick."""
+        return self._tick_now + (time.perf_counter() - self._tick_t0)
+
+    def _schedule(self):
+        """Grow the residents' pages (may evict), pick the decode set and
+        at most one prefill chunk (admitting if none is mid-prefill), and
+        copy a copy-on-write page.  Returns (pf, decode_slots)."""
+        sched = self.sched
         # decode set: resident + prefilled, with page headroom (may evict).
         # Parked states (prefill tier, awaiting migration) are excluded:
         # their KV is frozen until the receiver copies it.
@@ -302,27 +324,35 @@ class ServeEngine:
         # replica never prefills: its requests arrive pre-filled via adopt.
         pf = sched.mid_prefill() if self.ecfg.role != "decode" else None
         if pf is None and self.ecfg.role != "decode":
-            pf = sched.try_admit(self.alloc, now,
+            at = self._clock()
+            pf = sched.try_admit(self.alloc, at,
                                  prefix_cache=self.prefix_cache)
-            if pf is not None and pf.cached_tokens:
-                self.tel.record("prefix_hit", rid=pf.req.rid,
-                                cached_tokens=pf.cached_tokens,
-                                shared_pages=pf.n_shared_pages,
-                                cow=pf.cow_page is not None)
+            if pf is not None:
+                mark("serve/admit", rid=pf.req.rid, at=at)
+                if pf.cached_tokens:
+                    self.tel.record("prefix_hit", rid=pf.req.rid,
+                                    cached_tokens=pf.cached_tokens,
+                                    shared_pages=pf.n_shared_pages,
+                                    cow=pf.cow_page is not None)
         if pf is not None and pf.cow_page is not None:
             # whole-prompt hit: duplicate the boundary page so the
             # recomputed final-token row writes a PRIVATE copy and the
             # shared original stays immutable
             src, dst = pf.cow_page
             pf.cow_page = None
-            ctx = self.plan.mesh if self.plan.mesh is not None \
-                else contextlib.nullcontext()
-            with ctx:
+            with self._mesh():
                 self.pools = copy_page(self.pools, jnp.int32(src),
                                        jnp.int32(dst))
-        if pf is None and not decode_slots:
-            return False
+        return pf, decode_slots
 
+    def _mesh(self):
+        return self.plan.mesh if self.plan.mesh is not None \
+            else contextlib.nullcontext()
+
+    def _inputs(self, pf: Optional[RequestState], decode_slots):
+        """The step's device arguments and static shape for this tick's
+        decode set and prefill chunk.  Returns (args, static, chunk)."""
+        ecfg, sched = self.ecfg, self.sched
         B, mp = ecfg.max_batch, ecfg.max_pages_per_req
         pt = np.zeros((B, mp), np.int32)
         pos = np.zeros((B,), np.int32)
@@ -340,7 +370,6 @@ class ServeEngine:
         bucket = None
         history = False
         chunk = 0
-        final_chunk = False
         pf_tokens = np.zeros((1, 1), np.int32)
         pf_len = np.int32(0)
         pf_ptrow = np.zeros((mp,), np.int32)
@@ -351,7 +380,6 @@ class ServeEngine:
             chunk = P - pf.prefill_pos
             if ecfg.prefill_chunk:
                 chunk = min(chunk, ecfg.prefill_chunk)
-            final_chunk = pf.prefill_pos + chunk >= P
             bucket = min(b for b in ecfg.prefill_buckets if b >= chunk)
             pf_tokens = np.zeros((1, bucket), np.int32)
             pf_tokens[0, :chunk] = pf.req.prompt[
@@ -363,20 +391,54 @@ class ServeEngine:
             pf_temp = np.float32(pf.req.temperature)
 
         key = jax.random.fold_in(self._key, self._tick_count)
-        ctx = self.plan.mesh if self.plan.mesh is not None \
-            else contextlib.nullcontext()
-        with ctx:
-            self.pools, out = self._step_fn(
-                self.params, self.pools, jnp.asarray(pt), jnp.asarray(last),
+        args = (self.params, self.pools, jnp.asarray(pt), jnp.asarray(last),
                 jnp.asarray(pos), jnp.asarray(active), jnp.asarray(temps),
                 jnp.asarray(pf_tokens), pf_len, jnp.asarray(pf_ptrow),
-                pf_start, pf_temp, key, bucket=bucket,
-                any_decode=bool(decode_slots), history=history)
-        out = jax.device_get(out)
+                pf_start, pf_temp, key)
+        static = dict(bucket=bucket, any_decode=bool(decode_slots),
+                      history=history)
+        return args, static, chunk
+
+    def _tick(self, results: Dict[int, dict], attrs: dict) -> bool:
+        """tick's body, one child span per phase; fills the tick's attrs."""
+        with Span("serve/schedule"):
+            pf, decode_slots = self._schedule()
+        attrs["n_waiting"] = len(self.sched.waiting)
+        if pf is None and not decode_slots:
+            return False
+
+        with self._mesh():
+            with Span("serve/inputs"):
+                args, static, chunk = self._inputs(pf, decode_slots)
+            attrs.update(n_decode=len(decode_slots), chunk=int(chunk),
+                         bucket=static["bucket"], history=static["history"],
+                         rid=pf.req.rid if pf is not None else None)
+            # the warm-up's recording wrapper has no jit cache to read
+            cache_size = getattr(self._step_fn, "_cache_size", None)
+            n_compiled = cache_size() if cache_size else 0
+            with Span("serve/dispatch"):
+                self.pools, out = self._step_fn(*args, **static)
+        if cache_size and cache_size() > n_compiled:
+            attrs["compiled"] = True
+            self.tel.counter("serve_step_compiles_total").inc()
+        with Span("serve/wait"):
+            out = jax.device_get(out)
+
+        with Span("serve/emit"):
+            self._emit_tick(pf, decode_slots, static["bucket"], chunk, out,
+                            results)
+        return True
+
+    def _emit_tick(self, pf, decode_slots, bucket, chunk, out, results
+                   ) -> None:
+        """Token bookkeeping after the step: counters, the prefilled
+        request's progress (its first token on the final chunk, then the
+        prefix insert / handoff), every decoded token."""
+        ecfg, sched, tel = self.ecfg, self.sched, self.tel
+        delivered = self._clock()
         self._tick_count += 1
         self.max_concurrent = max(self.max_concurrent,
                                   len(decode_slots) + (pf is not None))
-        tel = self.tel
         tel.counter("serve_ticks_total").inc()
         tel.counter("serve_decode_tokens_total").inc(len(decode_slots))
         if pf is not None:
@@ -395,7 +457,7 @@ class ServeEngine:
 
         if pf is not None:
             pf.prefill_pos += chunk
-            if final_chunk:
+            if pf.prefill_pos >= len(pf.req.prompt):
                 if self.prefix_cache is not None:
                     # every full prompt page is now written and stable
                     # (decode rows land beyond them) -> publish the prefix;
@@ -404,7 +466,7 @@ class ServeEngine:
                                              self.alloc)
                 # only the last chunk's logits are meaningful (the prompt's
                 # final position) — intermediate chunks just fill pages
-                self._emit(pf, int(out["prefill_tok"]), now, results)
+                self._emit(pf, int(out["prefill_tok"]), delivered, results)
                 if self.ecfg.role == "prefill" \
                         and self.sched.active.get(pf.slot) is pf:
                     # prefill tier: done here — park (slot/pages/budget stay
@@ -420,16 +482,18 @@ class ServeEngine:
                 st = sched.active.get(s)
                 if st is None:
                     continue
-                self._emit(st, int(toks[s]), now, results)
-        return True
+                self._emit(st, int(toks[s]), delivered, results)
 
     def _emit(self, st: RequestState, tok: int, now: float,
               results: Dict[int, dict]) -> None:
+        """Deliver one token; `now` is its delivery time on the request
+        clock."""
         st.generated.append(tok)
         st.prefilled = True
         self.total_decoded += 1
         if st.first_token_time is None:
             st.first_token_time = now
+            mark("serve/first_token", rid=st.req.rid, at=now)
             self.tel.histogram("serve_ttft_ms", edges=_LAT_BUCKETS).observe(
                 (now - st.req.arrival_time) * 1e3)
         elif st.last_token_time is not None:
@@ -438,6 +502,7 @@ class ServeEngine:
         st.last_token_time = now
         if st.done(self.ecfg.eos_id):
             self.sched.finish(st.slot, self.alloc, now)
+            mark("serve/finish", rid=st.req.rid, at=now)
             self.tel.counter("serve_finished_total").inc()
             n_tok = len(st.generated)
             ttft_ms = (st.first_token_time - st.req.arrival_time) * 1e3
@@ -482,9 +547,7 @@ class ServeEngine:
                             temperature=st.req.temperature,
                             prompt=tuple(st.req.prompt),
                             generated=tuple(st.generated))
-        ctx = self.plan.mesh if self.plan.mesh is not None \
-            else contextlib.nullcontext()
-        with ctx:
+        with self._mesh():
             return self.codec.pack(self.pools, ship, meta)
 
     def reserve_for_adopt(self, req):
@@ -528,9 +591,7 @@ class ServeEngine:
         tree so later migrations/admissions of the same tenant re-share
         these pages."""
         if fresh:
-            ctx = self.plan.mesh if self.plan.mesh is not None \
-                else contextlib.nullcontext()
-            with ctx:
+            with self._mesh():
                 self.pools = self.codec.scatter(self.pools, payload, fresh)
         timing = timing or {}
         req = Request(prompt=list(meta.prompt),

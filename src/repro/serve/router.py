@@ -133,7 +133,13 @@ class ReplicaRouter:
             while pending and (not realtime
                                or pending[0].arrival_time <= now):
                 self.route(pending.popleft())
-            progressed = any([e.tick(now, results) for e in self.engines])
+            # each engine stamps its tokens on the request clock as it
+            # reads it at its own tick: the replicas ticked before it took
+            # their time, so the clock is read again for every engine
+            progressed = False
+            for e in self.engines:
+                if e.tick(time.perf_counter() - t0, results):
+                    progressed = True
             # Drain runs every loop and counts as progress: on a SATURATED
             # fleet every engine's tick can return False (all at budget, no
             # admissible head) while the handoff queue is nonempty — the old
@@ -141,7 +147,7 @@ class ReplicaRouter:
             # migration away from unblocking tripped the deadlock error.
             # Migration frees donor budget / fills decode slots, so crediting
             # it keeps the idle counter honest.
-            if self._drain(now, results):
+            if self._drain(time.perf_counter() - t0, results):
                 progressed = True
             if progressed:
                 idle_spins = 0

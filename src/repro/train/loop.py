@@ -25,6 +25,7 @@ from repro.core import casts
 from repro.core import quant as quant_stats
 from repro.data.pipeline import DataConfig, make_batch
 from repro.obs.sink import null_telemetry
+from repro.obs.trace import Span
 from repro.runtime import fault_injection
 from repro.runtime.fault_tolerance import ElasticTrainer
 
@@ -165,10 +166,10 @@ def run(train_step: Callable, state, data_cfg: DataConfig, *,
         # dispatch + device execution (to data-ready), fetch span the
         # blocking device->host copy of the metrics dict — still the loop's
         # ONE per-step fetch (guard flags + quant stats ride along).
-        with tel.span("device_step") as sp_dev:
+        with Span("train/device_step") as sp_dev:
             state, metrics = fn(state, batch)
             jax.block_until_ready(metrics)
-        with tel.span("host_fetch") as sp_fetch:
+        with Span("train/host_fetch") as sp_fetch:
             host_metrics = jax.device_get(metrics)
         loss = float(host_metrics["loss"])
         dt = time.monotonic() - t0

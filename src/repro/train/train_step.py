@@ -30,7 +30,7 @@ from repro.configs.base import ArchConfig
 from repro.core import quant
 from repro.core.recipes import Recipe
 from repro.models.lm import ParallelPlan, forward
-from repro.obs.trace import annotate
+from repro.obs.trace import annotate, stage_annotation
 from repro.optim import adamw, schedules
 from repro.train import guards
 
@@ -75,8 +75,9 @@ def make_train_step(cfg: ArchConfig, recipe: Recipe, plan: ParallelPlan,
         lr_scale = schedules.warmup_cosine(
             state["opt"]["step"], total_steps=total_steps,
             warmup_steps=warmup_steps)
-        new_params, new_opt, opt_metrics = adamw.apply_updates(
-            opt, params, grads, state["opt"], lr_scale=lr_scale)
+        with stage_annotation("optimizer"):
+            new_params, new_opt, opt_metrics = adamw.apply_updates(
+                opt, params, grads, state["opt"], lr_scale=lr_scale)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss
@@ -453,66 +454,69 @@ def _make_dist_train_step(cfg: ArchConfig, recipe: Recipe, plan: ParallelPlan,
             sq_sens = [jnp.sum(jnp.square(g)) for g in sens_g.values()]
             gnorm = jnp.sqrt(sq_owned + (jnp.sum(jnp.stack(sq_sens))
                                          if sq_sens else jnp.float32(0.0)))
-            clip = adamw.clip_factor(opt, gnorm)
-            step = opt_st["step"] + 1
-            b1c, b2c = adamw.bias_corrections(opt, step)
-            lr = opt.lr * schedules.warmup_cosine(
-                opt_st["step"], total_steps=total_steps,
-                warmup_steps=warmup_steps)
+            with stage_annotation("optimizer"):
+                clip = adamw.clip_factor(opt, gnorm)
+                step = opt_st["step"] + 1
+                b1c, b2c = adamw.bias_corrections(opt, step)
+                lr = opt.lr * schedules.warmup_cosine(
+                    opt_st["step"], total_steps=total_steps,
+                    warmup_steps=warmup_steps)
 
-            # ZeRO-1: update the owned shard, all-gather bf16 param shards
-            new_leaves, stacked_new, new_flat = {}, {}, []
-            for b, o_g, st_b in zip(layout.buckets, owned, opt_st["flat"]):
-                shard32 = None
-                if "master" not in st_b:
-                    rows_l = b.rows // n_dp
-                    idx = jax.lax.axis_index(axis)
-                    # flatten in the (bf16) param dtype, not f32: only the
-                    # owned 1/P shard is widened (fp8-class leaves are all
-                    # low-precision unless the user inits f32 params)
-                    fdt = jnp.float32 if any(
-                        pleaves[s.index].dtype == jnp.float32
-                        for s in b.slots) else jnp.bfloat16
-                    shard32 = jax.lax.dynamic_slice_in_dim(
-                        bucket_flat(b, pleaves, fdt), idx * rows_l,
-                        rows_l, 0).astype(jnp.float32)
-                new_shard, new_st = ost.flat_bucket_update(
-                    opt, pol, st_b, o_g, clip, lr, b1c, b2c, shard32)
-                full = grad_comm.all_gather_shard(new_shard, axis)
-                for key, piece in bucket_scatter(b, full, pleaves).items():
-                    if isinstance(key, tuple):      # layered: (index, layer)
-                        stacked_new.setdefault(key[0], {})[key[1]] = piece
-                    else:
-                        new_leaves[key] = piece
-                new_flat.append(new_st)
-            # layered buckets update one layer slice at a time; restack them
-            for i, pieces in stacked_new.items():
-                new_leaves[i] = jnp.stack(
-                    [pieces[l] for l in range(pleaves[i].shape[0])])
+                # ZeRO-1: update the owned shard, all-gather bf16 param
+                # shards
+                new_leaves, stacked_new, new_flat = {}, {}, []
+                for b, o_g, st_b in zip(layout.buckets, owned,
+                                        opt_st["flat"]):
+                    shard32 = None
+                    if "master" not in st_b:
+                        rows_l = b.rows // n_dp
+                        idx = jax.lax.axis_index(axis)
+                        # flatten in the (bf16) param dtype, not f32: only the
+                        # owned 1/P shard is widened (fp8-class leaves are all
+                        # low-precision unless the user inits f32 params)
+                        fdt = jnp.float32 if any(
+                            pleaves[s.index].dtype == jnp.float32
+                            for s in b.slots) else jnp.bfloat16
+                        shard32 = jax.lax.dynamic_slice_in_dim(
+                            bucket_flat(b, pleaves, fdt), idx * rows_l,
+                            rows_l, 0).astype(jnp.float32)
+                    new_shard, new_st = ost.flat_bucket_update(
+                        opt, pol, st_b, o_g, clip, lr, b1c, b2c, shard32)
+                    full = grad_comm.all_gather_shard(new_shard, axis)
+                    for key, piece in bucket_scatter(b, full, pleaves).items():
+                        if isinstance(key, tuple):  # layered: (index, layer)
+                            stacked_new.setdefault(key[0], {})[key[1]] = piece
+                        else:
+                            new_leaves[key] = piece
+                    new_flat.append(new_st)
+                # layered buckets update one layer slice at a time; restack
+                for i, pieces in stacked_new.items():
+                    new_leaves[i] = jnp.stack(
+                        [pieces[l] for l in range(pleaves[i].shape[0])])
 
-            # sensitive leaves: replicated classic update (f32 state)
-            sens_st = opt_st["sens"]
-            new_sens = {"m": {}, "v": {}}
-            if "master" in sens_st:
-                new_sens["master"] = {}
-            for i, pth in layout.sensitive:
-                p = pleaves[i]
-                g32 = sens_g[pth] * clip
-                base = sens_st["master"][pth] if "master" in sens_st \
-                    else p.astype(jnp.float32)
-                new_master, m_new, v_new = adamw.adamw_math(
-                    opt, g32, sens_st["m"][pth], sens_st["v"][pth], base,
-                    lr, b1c, b2c)
-                new_leaves[i] = new_master.astype(p.dtype)
-                new_sens["m"][pth] = m_new
-                new_sens["v"][pth] = v_new
+                # sensitive leaves: replicated classic update (f32 state)
+                sens_st = opt_st["sens"]
+                new_sens = {"m": {}, "v": {}}
                 if "master" in sens_st:
-                    new_sens["master"][pth] = new_master
+                    new_sens["master"] = {}
+                for i, pth in layout.sensitive:
+                    p = pleaves[i]
+                    g32 = sens_g[pth] * clip
+                    base = sens_st["master"][pth] if "master" in sens_st \
+                        else p.astype(jnp.float32)
+                    new_master, m_new, v_new = adamw.adamw_math(
+                        opt, g32, sens_st["m"][pth], sens_st["v"][pth], base,
+                        lr, b1c, b2c)
+                    new_leaves[i] = new_master.astype(p.dtype)
+                    new_sens["m"][pth] = m_new
+                    new_sens["v"][pth] = v_new
+                    if "master" in sens_st:
+                        new_sens["master"][pth] = new_master
 
-            new_params = jax.tree.unflatten(
-                treedef, [new_leaves[i] for i in range(len(pleaves))])
-            new_opt = {"step": step, "flat": tuple(new_flat),
-                       "sens": new_sens}
+                new_params = jax.tree.unflatten(
+                    treedef, [new_leaves[i] for i in range(len(pleaves))])
+                new_opt = {"step": step, "flat": tuple(new_flat),
+                           "sens": new_sens}
             # quant_* stats reduce by pmax (an anomaly ANYWHERE must trip
             # the replica-uniform flag); everything else stays pmean
             metrics = {k: jax.lax.pmax(v, axis) if k.startswith("quant_")
