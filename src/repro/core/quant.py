@@ -269,16 +269,6 @@ def _scale_shape(shape, tile):
     return tuple(s // t for s, t in zip(shape, tile))
 
 
-def _upsample_scale(scale: jax.Array, tile) -> jax.Array:
-    """Broadcast per-tile scales back to element resolution (materializes —
-    prefer _tiled_mul/_tiled_div, which broadcast through a reshape)."""
-    out = scale
-    for ax, t in enumerate(tile):
-        if t != 1:
-            out = jnp.repeat(out, t, axis=ax)
-    return out
-
-
 def _split_shape(shape, tile):
     """(n0, n1, ...) -> interleaved (n0/t0, t0, ...) with 1s for the scale."""
     xs, ss = [], []
@@ -293,10 +283,26 @@ def _split_shape(shape, tile):
 
 
 def _tiled_op(x, scale, tile, op):
-    """x <op> per-tile-scale WITHOUT materializing an upsampled scale tensor
-    (reshape-broadcast; §Perf: saves a full-size f32 round trip per Q/DQ)."""
-    xs, ss = _split_shape(x.shape, tile)
-    out = op(x.reshape(xs), scale.reshape(ss))
+    """x <op> per-tile-scale, the scale broadcast without relayouting x.
+
+    A TPU tiles the last two axes of an array in memory (sublanes x lanes).
+    Splitting the second-to-last axis into (n/t, t) moves no data (t is a
+    multiple of the sublane tile); splitting the last (lane) axis changes
+    the tiled layout: a copy of the whole tensor, or of a full-size
+    upsampled scale, in every call.  So every tiled axis but the last is
+    split, and along the last the small scale array is repeated to full
+    width instead: the convert, the scaling and the broadcast then fuse into
+    one pass that XLA feeds straight into a dot.  Row-wise tiles over
+    several tiles of the last axis are split instead: there the repeated
+    scale would be as large as x, and XLA's cost analysis of the training
+    step counts fewer bytes for the split."""
+    *lead, last = tile
+    if last == 1 or (all(t == 1 for t in lead) and x.shape[-1] > last):
+        xs, ss = _split_shape(x.shape, tile)
+        return op(x.reshape(xs), scale.reshape(ss)).reshape(x.shape)
+    xs, ss = _split_shape(x.shape[:-1], lead)
+    out = op(x.reshape(xs + x.shape[-1:]),
+             jnp.repeat(scale, last, axis=-1).reshape(ss + x.shape[-1:]))
     return out.reshape(x.shape)
 
 

@@ -55,12 +55,14 @@ def param_specs(cfg: ArchConfig, mesh) -> Any:
     attn_shard = _attn_param_bytes(cfg) > 2 ** 30
     emb_tp = _tp_ok(cfg.vocab_padded, tp)
 
-    def leaf_spec(path: str, ndim: int) -> P:
+    def leaf_spec(path: str, ndim: int, w8: bool = False) -> P:
         # `path` is the dotted key path WITHOUT the stacking dim; specs below
         # are written for the stacked array (leading None for the layer dim
-        # when ndim exceeds the per-layer rank).
+        # when ndim exceeds the per-layer rank).  w8: a field of a W8-resident
+        # QTensor leaf (serve/w8.py), whose `we13` is stored (E, D, g*Fe).
         name = path.split(".")[-1]
-        lead = (None,) * (ndim - _per_layer_rank(name))
+        rank = 3 if w8 and name == "we13" else _per_layer_rank(name)
+        lead = (None,) * (ndim - rank)
 
         def spec(*s):
             return P(*(lead + s))
@@ -84,6 +86,9 @@ def param_specs(cfg: ArchConfig, mesh) -> Any:
             return spec(fs, None, None)
         if name == "ws2":
             return spec(None, fs)
+        if name == "we13" and w8:             # (E, D, g*Fe)
+            ep = cfg.n_experts % tp == 0 and cfg.n_experts >= tp
+            return spec("model" if ep else None, fs, None)
         if name == "we13":                    # (E, D, g, Fe)
             if cfg.n_experts % tp == 0 and cfg.n_experts >= tp:
                 return spec("model", fs, None, None)          # EP
@@ -121,11 +126,12 @@ def tree_specs(cfg: ArchConfig, mesh, tree_shapes) -> Any:
     def to_spec(path, leaf):
         keys = [getattr(k, "key", getattr(k, "name", str(k))) for k in path]
         # QTensor leaves ('we13.data' / 'we13.scale') follow the parent rule
-        if keys and str(keys[-1]) in ("data", "scale") and len(keys) > 1:
+        w8 = len(keys) > 1 and str(keys[-1]) in ("data", "scale")
+        if w8:
             keys = keys[:-1]
         name = ".".join(str(k) for k in keys)
         ndim = len(leaf.shape)
-        base = ls(name, ndim)
+        base = ls(name, ndim, w8)
         if len(base) < ndim:
             base = P(*(tuple(base) + (None,) * (ndim - len(base))))
         if len(base) > ndim:

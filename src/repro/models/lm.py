@@ -355,8 +355,8 @@ def _moe_stage(cfg, recipe, plan, p, x, decode=False):
             # W8-resident: fp8 payload + po2 scales live on-chip; no gather,
             # no per-step weight quantization (serve/w8.py)
             from repro.core.fp8 import TILE as _T
-            from repro.serve.w8 import retile, w8_merge_gate
-            we13_r = w8_merge_gate(retile(we13_l, (1, _T, 1, _T)))
+            from repro.serve.w8 import retile
+            we13_r = retile(we13_l, (1, _T, _T))      # stored (E, D, g*Fe)
             we2_l = retile(we2_l, (1, _T, _T))
         else:
             if gather:
@@ -411,8 +411,8 @@ def _moe_stage(cfg, recipe, plan, p, x, decode=False):
         from repro.core.quant import QTensor as _QT2
         # QTensor weights: spec pytree matches (data, scale); scales shard
         # on the same (leading expert) axis
-        we13_spec = _QT2(data=P(e_spec0, None, None, None),
-                         scale=P(e_spec0, None, None, None),
+        we13_spec = _QT2(data=P(e_spec0, None, None),
+                         scale=P(e_spec0, None, None),
                          tile=we13.tile)
         we2_spec = _QT2(data=P(e_spec0, None, None),
                         scale=P(e_spec0, None, None), tile=we2.tile)

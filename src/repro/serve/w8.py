@@ -20,7 +20,7 @@ from repro.core.quant import QTensor, quantize
 # norms/router/biases stay f32, attention projections stay bf16 — they are
 # small and latency-critical)
 _W8_LEAVES = {
-    "we13": (1, 1, TILE, 1, TILE),   # (L, E, D, g, Fe)
+    "we13": (1, 1, TILE, 1, TILE),   # (L, E, D, g, Fe), stored (L, E, D, g*Fe)
     "we2": (1, 1, TILE, TILE),       # (L, E, Fe, D)
 }
 
@@ -37,8 +37,9 @@ def quantize_params_for_serving(params):
         if name in _W8_LEAVES:
             tile = _W8_LEAVES[name]
             if len(tile) == leaf.ndim and _pad_ok(leaf.shape, tile):
-                return quantize(leaf, tile, tag=f"q_w8_{name}",
-                                kind="fused_quantize")
+                q = quantize(leaf, tile, tag=f"q_w8_{name}",
+                             kind="fused_quantize")
+                return _merge_gate(q) if name == "we13" else q
         return leaf
 
     return jax.tree_util.tree_map_with_path(conv, params)
@@ -48,13 +49,16 @@ def is_w8(p) -> bool:
     return isinstance(p, QTensor)
 
 
-def w8_merge_gate(q: QTensor):
-    """(E, D, g, Fe) blockwise QTensor -> (E, D, g*Fe): exact block
-    relabeling (gate/up halves stay contiguous)."""
-    E, D, g, Fe = q.data.shape
-    return QTensor(data=q.data.reshape(E, D, g * Fe),
-                   scale=q.scale.reshape(E, D // TILE, g * Fe // TILE),
-                   tile=(1, TILE, TILE))
+def _merge_gate(q: QTensor) -> QTensor:
+    """(L, E, D, g, Fe) blockwise QTensor -> (L, E, D, g*Fe), the layout the
+    grouped GEMM reads.  Done once here: a TPU tiles the last two axes in
+    memory, so merging g and Fe in the step copies the whole payload on
+    every pass.  Exact block relabeling (Fe is whole tiles, so each block
+    lies in one of the gate/up halves)."""
+    *lead, g, fe = q.data.shape
+    return QTensor(data=q.data.reshape(*lead, g * fe),
+                   scale=q.scale.reshape(*q.scale.shape[:-2], -1),
+                   tile=(1, 1, TILE, TILE))
 
 
 def retile(q: QTensor, tile) -> QTensor:

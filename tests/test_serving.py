@@ -64,3 +64,19 @@ def test_w8_resident_weights_decode_matches_bf16_weights(setup):
     # the training recipe quantizes the same weights per step, so W8-resident
     # decode is numerically near-identical to the on-the-fly path
     assert cos > 0.999, cos
+
+
+def test_w8_we13_specs_follow_the_merged_layout(setup):
+    """The W8 `we13` is stored (L, E, D, g*Fe): its payload and scales
+    shard on the expert axis, like the bf16 (L, E, D, g, Fe) leaf."""
+    from repro.launch import sharding
+    cfg, mesh, _, params = setup
+    shapes = jax.eval_shape(quantize_params_for_serving, params)
+    we13 = shapes["layers"]["we13"]
+    L, E, D, g, Fe = params["layers"]["we13"].shape
+    assert we13.data.shape == (L, E, D, g * Fe)
+    specs = sharding.tree_specs(cfg, mesh, shapes)["layers"]
+    bf16 = sharding.tree_specs(cfg, mesh, params)["layers"]["we13"].spec
+    assert tuple(bf16)[:2] == (None, "model")
+    for leaf in (specs["we13"].data, specs["we13"].scale):
+        assert tuple(leaf.spec) == (None, "model", None, None)
