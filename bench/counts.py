@@ -7,8 +7,24 @@ Conventions: a multiply-add is 2 FLOPs; training is forward + backward =
 context of n keys costs 4 * n * H * hd per query per layer forward (QK and
 PV), and a packed training row of S tokens averages n = S/2.  The dense
 FFN counts its published width (10944), not the program's padded 11008.
+
+A configuration may hold a chip's share of its routed experts: its
+``n_routed_experts`` experts held here of ``published.n_routed_experts``,
+ids from ``first_routed_expert`` (default 0) on.  The router scores all
+``E`` published experts; the chip holds and computes ``Eh``, so a token's
+``k`` picks cost it ``k * Eh / E`` experts on average.
 """
 from __future__ import annotations
+
+
+def routed_experts(cfg: dict):
+    """(published routed experts E, the ids held here as a range)."""
+    Eh = cfg["n_routed_experts"]
+    E = cfg["published"].get("n_routed_experts", Eh)
+    first = cfg.get("first_routed_expert", 0)
+    if not 0 <= first <= first + Eh <= E:
+        raise ValueError(f"experts {first}..{first + Eh - 1} held of {E}")
+    return E, range(first, first + Eh)
 
 
 def _sizes(cfg: dict):
@@ -16,9 +32,10 @@ def _sizes(cfg: dict):
     H = cfg["num_attention_heads"]
     hd = cfg["arch"]["head_dim"]
     KV = cfg["num_key_value_heads"]
+    E, held = routed_experts(cfg)
     return dict(D=D, H=H, hd=hd, KV=KV, V=cfg["vocab_size"],
                 F=cfg["intermediate_size"], Fe=cfg["moe_intermediate_size"],
-                E=cfg["n_routed_experts"], k=cfg["num_experts_per_tok"],
+                E=E, Eh=len(held), k=cfg["num_experts_per_tok"],
                 ns=cfg["n_shared_experts"], nd=cfg["first_k_dense_replace"],
                 nm=cfg["num_hidden_layers"] - cfg["first_k_dense_replace"])
 
@@ -33,28 +50,29 @@ def expert_params(s) -> int:
 
 
 def n_params(cfg: dict) -> int:
-    """All parameters, embedding and head included."""
+    """All parameters held here, embedding and head included."""
     s = _sizes(cfg)
     D = s["D"]
     n = 2 * s["V"] * D + D                                  # embed, head, norm
     n += (s["nd"] + s["nm"]) * (attn_params(s) + 2 * D)
     n += s["nd"] * 3 * D * s["F"]
-    n += s["nm"] * (D * s["E"] + s["E"] * expert_params(s)
+    n += s["nm"] * (D * s["E"] + s["Eh"] * expert_params(s)
                     + s["ns"] * expert_params(s))
     return n
 
 
-def active_params(cfg: dict) -> int:
-    """Parameters one token multiplies through (the embedding is a lookup
-    and the router is counted): attention, dense FFN, k routed + shared
-    experts, router, head."""
+def active_params(cfg: dict) -> float:
+    """Parameters one token multiplies through here (the embedding is a
+    lookup and the router is counted): attention, dense FFN, the k * Eh / E
+    routed experts its picks land on here on average, shared experts, the
+    whole router, head."""
     s = _sizes(cfg)
     D = s["D"]
     n = (s["nd"] + s["nm"]) * attn_params(s)
     n += s["nd"] * 3 * D * s["F"]
-    n += s["nm"] * ((s["k"] + s["ns"]) * expert_params(s) + D * s["E"])
+    n += s["nm"] * (s["ns"] * expert_params(s) + D * s["E"])
     n += D * s["V"]
-    return n
+    return n + s["nm"] * s["k"] * s["Eh"] * expert_params(s) / s["E"]
 
 
 def attn_score_flops(cfg: dict, context: float) -> float:
@@ -86,12 +104,13 @@ def train_expert_scope(cfg: dict, tokens: int, kept_rows: float) -> dict:
     training step, forward and backward (3x forward), recomputation left
     out: the routed-expert FFN on the rows it kept (padding is not useful
     work) and the dense layer's FFN, which the program scopes as
-    ``stage/expert`` too.  Bytes: every expert and dense-FFN weight read in
-    bf16 forward and backward, and its gradient written in f32."""
+    ``stage/expert`` too.  Bytes: every expert held here and the dense-FFN
+    weight read in bf16 forward and backward, and their gradients written
+    in f32."""
     s = _sizes(cfg)
     flops = 3.0 * (routed_expert_flops(cfg, kept_rows)
                    + dense_ffn_flops(cfg, tokens))
-    w = s["nm"] * s["E"] * expert_params(s) + s["nd"] * 3 * s["D"] * s["F"]
+    w = s["nm"] * s["Eh"] * expert_params(s) + s["nd"] * 3 * s["D"] * s["F"]
     return {"flops": flops, "bytes": w * (2 + 2 + 4)}
 
 
@@ -102,9 +121,9 @@ def serve_token_flops(cfg: dict, context: float) -> float:
 
 
 def w8_expert_bytes(cfg: dict) -> int:
-    """Bytes of the routed experts in e4m3 with one f32 scale per 128x128
-    block, over all MoE layers: what one forward pass that touches every
-    expert reads."""
+    """Bytes of the routed experts held here in e4m3 with one f32 scale per
+    128x128 block, over all MoE layers: what one forward pass that touches
+    every expert reads."""
     s = _sizes(cfg)
     per = expert_params(s)
-    return s["nm"] * s["E"] * (per + 4 * per // (128 * 128))
+    return s["nm"] * s["Eh"] * (per + 4 * per // (128 * 128))

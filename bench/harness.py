@@ -9,7 +9,9 @@ adding files:
   bench/cells/<workload>.json     the cell's own numbers: rate, limits
   bench/metrics/<metric>.py       one reader per metric (end-to-end and
                                   per-layer alike)
-  bench/reference/<name>.py       the plain reference a configuration names
+  bench/reference/<name>.py       the plain reference a configuration names,
+                                  and, if it defines leaves(cfg, padded),
+                                  the parameter tree (bench/weights.py)
 
 A reader module declares LAYER, UNIT, BETTER, MOVES and SOURCE as
 ``BENCHMARK.json`` has them and defines ``read(run) -> float | None``;
@@ -28,6 +30,9 @@ from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
+# set by Cell.resolve on the configuration: the root it was found under, where
+# weights.leaves looks for its reference's own leaf list
+ROOT_KEY = "_root"
 
 
 class NoChip(RuntimeError):
@@ -95,7 +100,9 @@ class Cell:
         bench = bench or benchmark(root)
         w = find(bench["workloads"], name, "workload")
         cfg_entry = find(bench["configs"], w["config"], "config")
-        return cls(name, w, load_json(root / cfg_entry["file"]),
+        cfg = load_json(root / cfg_entry["file"])
+        cfg[ROOT_KEY] = str(root)
+        return cls(name, w, cfg,
                    load_json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
                    load_json(root / "bench" / "cells" / f"{name}.json"), root)
 

@@ -6,15 +6,25 @@ reference never reads anything the program made.  Every leaf is drawn from
 its own key, ``fold_in(seed_key, leaf_index)``, at its published (live)
 shape and then zero-padded to the shape the program runs (the dense FFN's
 10944 columns padded to 11008), so the reference regenerates any leaf alone.
+
+The tree is ``default_leaves`` unless the configuration's reference module
+(``bench/reference/<reference>.py``, under the root the cell was resolved
+from) defines ``leaves(cfg, padded)``.  Such a list may add leaves or
+replace some of the default ones; it puts the leaves it adds after the
+default ones, so those keep their indices and their draws.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+from pathlib import Path
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bench import counts, harness
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,14 +42,31 @@ def seed_key(seed: int):
 
 
 def leaves(cfg: dict, padded: bool = True):
-    """Leaf specs of the program's parameter tree, in a fixed order."""
+    """Leaf specs of the program's parameter tree, in a fixed order: the
+    configuration's reference's own list where it has one."""
+    root = cfg.get(harness.ROOT_KEY, str(harness.ROOT))
+    own = _reference_leaves(root, cfg["reference"])
+    return (own or default_leaves)(cfg, padded)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_leaves(root: str, name: str):
+    return getattr(harness.reference(Path(root), {"reference": name}),
+                   "leaves", None)
+
+
+def default_leaves(cfg: dict, padded: bool = True):
+    """DeepSeek-V2's tree with GQA attention.  The router scores every
+    published routed expert; the expert stacks hold the experts held here
+    (``counts.routed_experts``)."""
     a = cfg["arch"]
     D, H, hd = cfg["hidden_size"], cfg["num_attention_heads"], a["head_dim"]
     KV = cfg["num_key_value_heads"]
     V = cfg["vocab_size"]
     F_live = cfg["intermediate_size"]
     F = a["d_ff"] if padded else F_live
-    E, Fe = cfg["n_routed_experts"], cfg["moe_intermediate_size"]
+    E, held = counts.routed_experts(cfg)
+    Eh, Fe = len(held), cfg["moe_intermediate_size"]
     Fs = cfg["n_shared_experts"] * Fe
     nd = cfg["first_k_dense_replace"]
     nm = cfg["num_hidden_layers"] - nd
@@ -67,9 +94,9 @@ def leaves(cfg: dict, padded: bool = True):
                      out_std)]
     out += attn("layers", nm)
     out += [Leaf(("layers", "w_router"), (nm, D, E), (nm, D, E), f32, std),
-            Leaf(("layers", "we13"), (nm, E, D, 2, Fe), (nm, E, D, 2, Fe), bf,
-                 std),
-            Leaf(("layers", "we2"), (nm, E, Fe, D), (nm, E, Fe, D), bf,
+            Leaf(("layers", "we13"), (nm, Eh, D, 2, Fe), (nm, Eh, D, 2, Fe),
+                 bf, std),
+            Leaf(("layers", "we2"), (nm, Eh, Fe, D), (nm, Eh, Fe, D), bf,
                  out_std),
             Leaf(("layers", "ws13"), (nm, D, 2, Fs), (nm, D, 2, Fs), bf, std),
             Leaf(("layers", "ws2"), (nm, Fs, D), (nm, Fs, D), bf, out_std)]

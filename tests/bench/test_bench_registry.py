@@ -147,3 +147,248 @@ def test_new_pieces_are_found_by_name(tmp_path):
     # the cell that was there before still resolves as it did
     assert harness.Cell.resolve("dsv2lite-train-l2.pack4k",
                                 tmp_path).mix["seq_len"] == 4096
+
+
+SHARE_REFERENCE = '''"""A share configuration's reference: the default tree, then
+a router bias."""
+from bench import weights
+
+
+def leaves(cfg, padded):
+    nm = cfg["num_hidden_layers"] - cfg["first_k_dense_replace"]
+    E = cfg["published"]["n_routed_experts"]
+    return weights.default_leaves(cfg, padded) + [
+        weights.Leaf(("layers", "e_score_correction_bias"), (nm, E), (nm, E),
+                     "float32", 0.02)]
+'''
+
+
+def share_cfg(cfg):
+    """4 of 16 routed experts held, ids 4..7, and a reference of its own."""
+    cfg.update({"name": "share-l2", "n_routed_experts": 4,
+                "first_routed_expert": 4, "reference": "share_ref",
+                "reduced": cfg["reduced"] + ["n_routed_experts"]})
+    cfg["published"]["n_routed_experts"] = 16
+    cfg["arch"]["n_experts"] = 4
+    return cfg
+
+
+def test_share_config_with_its_own_leaves_is_found_by_name(tmp_path):
+    """A configuration that holds a share of its routed experts, and whose
+    reference brings its own leaf list, added as new files only."""
+    import jax
+
+    shutil.copytree(ROOT / "bench", tmp_path / "bench")
+    cfg = share_cfg(harness.load_json(
+        ROOT / "bench" / "configs" / "dsv2lite-train-l2.json"))
+    (tmp_path / "bench" / "configs" / "share-l2.json").write_text(
+        json.dumps(cfg))
+    (tmp_path / "bench" / "reference" / "share_ref.py").write_text(
+        SHARE_REFERENCE)
+    (tmp_path / "bench" / "cells" / "share-l2.pack4k.json").write_text(
+        json.dumps({"limits": {"loss_gap": 1.0}}))
+    b = bench()
+    b["configs"].append({"name": "share-l2", "source": "x",
+                         "file": "bench/configs/share-l2.json",
+                         "reduced": cfg["reduced"], "why": "x"})
+    b["workloads"].append({"name": "share-l2.pack4k", "config": "share-l2",
+                           "traffic": "pack4k", "chips": 1, "why": "x"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+
+    cell = harness.Cell.resolve("share-l2.pack4k", tmp_path)
+    got = weights.leaves(cell.cfg)
+    shape = {lf.path: lf.shape for lf in got}
+    D, Fe = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    assert shape[("layers", "w_router")] == (1, D, 16)
+    assert shape[("layers", "we13")] == (1, 4, D, 2, Fe)
+    assert shape[("layers", "we2")] == (1, 4, Fe, D)
+    assert got[-1].path == ("layers", "e_score_correction_bias")
+    assert got[-1].shape == (1, 16)
+    # every default leaf keeps its index, so its fold_in draw
+    default = weights.default_leaves(cell.cfg)
+    assert got[:-1] == default
+    plain = weights.leaves(harness.Cell.resolve("dsv2lite-train-l2.pack4k",
+                                                tmp_path).cfg)
+    assert [lf.path for lf in plain] == [lf.path for lf in default]
+    # the tree draws: the bias is the last leaf, drawn from its own index
+    tree = jax.eval_shape(lambda k: weights.make_tree(cell.cfg, k),
+                          weights.seed_key(2 ** 40 + 3))
+    assert tree["layers"]["e_score_correction_bias"].shape == (1, 16)
+    bias = ("layers", "e_score_correction_bias")
+    assert weights.leaf_index(cell.cfg, bias) == len(default)
+    # the same files under the repository's root are not there to be found
+    with pytest.raises(FileNotFoundError):
+        weights.leaves(dict(cell.cfg, **{harness.ROOT_KEY: str(ROOT)}))
+
+
+def test_share_draws_keep_the_default_leaves_values(tmp_path):
+    """At a tiny size, every leaf the share tree has in common with the
+    whole tree is bitwise the same draw."""
+    import jax
+    import numpy as np
+
+    (tmp_path / "bench" / "reference").mkdir(parents=True)
+    (tmp_path / "bench" / "reference" / "share_ref.py").write_text(
+        SHARE_REFERENCE)
+    whole = harness.load_json(ROOT / "bench" / "configs" /
+                              "dsv2lite-serve-l5.json")
+    bench_tiny.shrink_cfg(whole)
+    share = share_cfg(json.loads(json.dumps(whole)))
+    share[harness.ROOT_KEY] = str(tmp_path)
+    key = weights.seed_key(2 ** 40 + 3)
+    a = jax.jit(lambda k: weights.make_tree(whole, k))(key)
+    b = jax.jit(lambda k: weights.make_tree(share, k))(key)
+    same = 0
+    for lf in weights.leaves(whole):
+        x, y = a, b
+        for p in lf.path:
+            x, y = x[p], y[p]
+        if x.shape == y.shape:
+            assert np.array_equal(np.asarray(x), np.asarray(y)), lf.path
+            same += 1
+    assert same == len(weights.leaves(whole)) - 3    # router, we13, we2
+    assert b["layers"]["w_router"].shape == (4, 256, 16)
+    assert b["layers"]["e_score_correction_bias"].shape == (4, 16)
+
+
+# weights.leaves of the two configurations as the parent commit drew them:
+# (path, shape, live shape where it differs, dtype, std)
+BF, F32, STD, OUT = "bfloat16", "float32", 0.02, 0.003849001794597505
+PINNED_LEAVES = {
+    "dsv2lite-train-l2": [
+        ("embed", (12800, 2048), None, BF, STD),
+        ("final_norm_s", (2048,), None, F32, 0.0),
+        ("lm_head", (2048, 12800), None, BF, STD),
+        ("dense_layers.ln1_s", (1, 2048), None, F32, 0.0),
+        ("dense_layers.ln2_s", (1, 2048), None, F32, 0.0),
+        ("dense_layers.wq", (1, 2048, 2048), None, BF, STD),
+        ("dense_layers.wk", (1, 2048, 2048), None, BF, STD),
+        ("dense_layers.wv", (1, 2048, 2048), None, BF, STD),
+        ("dense_layers.wo", (1, 2048, 2048), None, BF, OUT),
+        ("dense_layers.w13", (1, 2048, 2, 11008), (1, 2048, 2, 10944), BF,
+         STD),
+        ("dense_layers.w2", (1, 11008, 2048), (1, 10944, 2048), BF, OUT),
+        ("layers.ln1_s", (1, 2048), None, F32, 0.0),
+        ("layers.ln2_s", (1, 2048), None, F32, 0.0),
+        ("layers.wq", (1, 2048, 2048), None, BF, STD),
+        ("layers.wk", (1, 2048, 2048), None, BF, STD),
+        ("layers.wv", (1, 2048, 2048), None, BF, STD),
+        ("layers.wo", (1, 2048, 2048), None, BF, OUT),
+        ("layers.w_router", (1, 2048, 64), None, F32, STD),
+        ("layers.we13", (1, 64, 2048, 2, 1408), None, BF, STD),
+        ("layers.we2", (1, 64, 1408, 2048), None, BF, OUT),
+        ("layers.ws13", (1, 2048, 2, 2816), None, BF, STD),
+        ("layers.ws2", (1, 2816, 2048), None, BF, OUT),
+    ],
+    "dsv2lite-serve-l5": [
+        ("embed", (12800, 2048), None, BF, STD),
+        ("final_norm_s", (2048,), None, F32, 0.0),
+        ("lm_head", (2048, 12800), None, BF, STD),
+        ("dense_layers.ln1_s", (1, 2048), None, F32, 0.0),
+        ("dense_layers.ln2_s", (1, 2048), None, F32, 0.0),
+        ("dense_layers.wq", (1, 2048, 2048), None, BF, STD),
+        ("dense_layers.wk", (1, 2048, 2048), None, BF, STD),
+        ("dense_layers.wv", (1, 2048, 2048), None, BF, STD),
+        ("dense_layers.wo", (1, 2048, 2048), None, BF, OUT),
+        ("dense_layers.w13", (1, 2048, 2, 11008), (1, 2048, 2, 10944), BF,
+         STD),
+        ("dense_layers.w2", (1, 11008, 2048), (1, 10944, 2048), BF, OUT),
+        ("layers.ln1_s", (4, 2048), None, F32, 0.0),
+        ("layers.ln2_s", (4, 2048), None, F32, 0.0),
+        ("layers.wq", (4, 2048, 2048), None, BF, STD),
+        ("layers.wk", (4, 2048, 2048), None, BF, STD),
+        ("layers.wv", (4, 2048, 2048), None, BF, STD),
+        ("layers.wo", (4, 2048, 2048), None, BF, OUT),
+        ("layers.w_router", (4, 2048, 64), None, F32, STD),
+        ("layers.we13", (4, 64, 2048, 2, 1408), None, BF, STD),
+        ("layers.we2", (4, 64, 1408, 2048), None, BF, OUT),
+        ("layers.ws13", (4, 2048, 2, 2816), None, BF, STD),
+        ("layers.ws2", (4, 2816, 2048), None, BF, OUT),
+    ],
+}
+# sha256 (first 16 hex digits) of each leaf of make_tree at the
+# bench_tiny size, seed 2**40 + 3, as the parent commit drew it
+PINNED_DIGESTS = {
+    "dsv2lite-train-l2": {
+        "embed": "f327042d8fadd811",
+        "final_norm_s": "5f70bf18a0860070",
+        "lm_head": "2ff5982ed34dae4a",
+        "dense_layers.ln1_s": "5f70bf18a0860070",
+        "dense_layers.ln2_s": "5f70bf18a0860070",
+        "dense_layers.wq": "e30228a3912772eb",
+        "dense_layers.wk": "73a44821b7e9d0f6",
+        "dense_layers.wv": "2e20c9c94ee8b1a2",
+        "dense_layers.wo": "c7cb8aa3667aaa20",
+        "dense_layers.w13": "486c7d56c4fe6752",
+        "dense_layers.w2": "87dc33b9e2d6e0ce",
+        "layers.ln1_s": "5f70bf18a0860070",
+        "layers.ln2_s": "5f70bf18a0860070",
+        "layers.wq": "e8cde18577170cb8",
+        "layers.wk": "19e505ba218995f1",
+        "layers.wv": "748dd549002f0099",
+        "layers.wo": "ce0925341d2aa6ea",
+        "layers.w_router": "d3924baa1fc6ba75",
+        "layers.we13": "a0a0a2d48e900e4f",
+        "layers.we2": "a7e8393b50da9e47",
+        "layers.ws13": "22a63c58f5d90956",
+        "layers.ws2": "79c6cd990095b81a",
+    },
+    "dsv2lite-serve-l5": {
+        "embed": "f327042d8fadd811",
+        "final_norm_s": "5f70bf18a0860070",
+        "lm_head": "2ff5982ed34dae4a",
+        "dense_layers.ln1_s": "5f70bf18a0860070",
+        "dense_layers.ln2_s": "5f70bf18a0860070",
+        "dense_layers.wq": "e30228a3912772eb",
+        "dense_layers.wk": "73a44821b7e9d0f6",
+        "dense_layers.wv": "2e20c9c94ee8b1a2",
+        "dense_layers.wo": "c7cb8aa3667aaa20",
+        "dense_layers.w13": "486c7d56c4fe6752",
+        "dense_layers.w2": "87dc33b9e2d6e0ce",
+        "layers.ln1_s": "ad7facb2586fc6e9",
+        "layers.ln2_s": "ad7facb2586fc6e9",
+        "layers.wq": "938f927544a4c612",
+        "layers.wk": "63e0c76baf243448",
+        "layers.wv": "48c9ed99822cf8a1",
+        "layers.wo": "ddc312304c66d10a",
+        "layers.w_router": "415a2d6b2860e375",
+        "layers.we13": "b8c9e56d94f6d984",
+        "layers.we2": "2b88b70616173388",
+        "layers.ws13": "896f7649a03e043a",
+        "layers.ws2": "82c432b807f135a4",
+    },
+}
+
+@pytest.mark.parametrize("name", sorted(PINNED_LEAVES))
+def test_existing_configs_keep_their_leaves(name):
+    cell = next(w["name"] for w in bench()["workloads"] if w["config"] == name)
+    cfg = harness.Cell.resolve(cell, ROOT).cfg
+    got = [(".".join(lf.path), lf.shape,
+            None if lf.live == lf.shape else lf.live, lf.dtype, lf.std)
+           for lf in weights.leaves(cfg)]
+    assert got == PINNED_LEAVES[name]
+    assert not hasattr(harness.reference(ROOT, cfg), "leaves")
+    # unpadded, every leaf is drawn and held at its live shape
+    assert [lf.shape for lf in weights.leaves(cfg, padded=False)] == \
+        [lf.live for lf in weights.leaves(cfg)]
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_DIGESTS))
+def test_existing_configs_draw_bitwise_the_same_tree(name):
+    import hashlib
+
+    import jax
+    import numpy as np
+
+    cfg = harness.load_json(ROOT / "bench" / "configs" / f"{name}.json")
+    bench_tiny.shrink_cfg(cfg)
+    tree = jax.jit(lambda k: weights.make_tree(cfg, k))(
+        weights.seed_key(2 ** 40 + 3))
+    got = {}
+    for lf in weights.leaves(cfg):
+        x = tree
+        for p in lf.path:
+            x = x[p]
+        got[".".join(lf.path)] = hashlib.sha256(
+            np.asarray(x).tobytes()).hexdigest()[:16]
+    assert got == PINNED_DIGESTS[name]
